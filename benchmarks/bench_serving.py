@@ -265,9 +265,9 @@ def fleet(seed=0, *, rate_x=10.0, n_requests=48, max_batch=8, max_len=64,
           bucket=32, slo_ms=2000.0, base_rps=None):
     """replicas section: open-loop Poisson load at ``rate_x`` × the
     measured single-dense-engine request rate, swept over 1×dense /
-    1×paged / 2×paged replica fleets on one shared FIFO."""
-    import jax
-
+    1×paged / 2×paged replica fleets on one shared FIFO.  Runs before
+    anything in this process touches JAX: on a TPU host each replica
+    process holds its own chip."""
     from repro.inference import chain_key, tail_key
     from repro.launch.replicas import make_shared_trace, run_fleet
 
@@ -322,7 +322,7 @@ def fleet(seed=0, *, rate_x=10.0, n_requests=48, max_batch=8, max_len=64,
                                  affinity="prompt" if kv == "paged"
                                  else None, **common)
     return {
-        "platform": jax.default_backend(),
+        "platform": sweep["dense_x1"]["platform"],
         "base_rps": round(float(base_rps), 3),
         "rate_x": rate_x,
         "offered_rps": round(float(rate), 3),
@@ -376,6 +376,17 @@ def main(argv=None):
               ["scheduler", "paged"] + ([] if args.smoke else ["replicas"]))
 
     sections = {}
+    # the fleet first: its replica processes need the chips this process
+    # would hold once the single-engine sections below touch JAX
+    if "replicas" in wanted:
+        sections["replicas"] = fleet(seed=args.seed, rate_x=args.rate_x,
+                                     max_batch=args.max_batch)
+        f = sections["replicas"]
+        print(f"[bench_serving] fleet @ {f['offered_rps']} rps "
+              f"({f['rate_x']}x): paged_x2/dense_x1 = "
+              f"{f['fleet_speedup_x']}x tokens/s, SLO "
+              f"{f['sweep']['paged_x2']['slo_attainment']:.0%} vs "
+              f"{f['sweep']['dense_x1']['slo_attainment']:.0%}")
     if "scheduler" in wanted:
         sections["scheduler"] = compare(smoke=args.smoke, seed=args.seed,
                                         max_batch=args.max_batch)
@@ -391,15 +402,6 @@ def main(argv=None):
               f"(skips={p['paged']['prefill_skips']}, "
               f"hits={p['paged']['prefix_hits']}, "
               f"cow={p['paged']['cow_copies']})")
-    if "replicas" in wanted:
-        sections["replicas"] = fleet(seed=args.seed, rate_x=args.rate_x,
-                                     max_batch=args.max_batch)
-        f = sections["replicas"]
-        print(f"[bench_serving] fleet @ {f['offered_rps']} rps "
-              f"({f['rate_x']}x): paged_x2/dense_x1 = "
-              f"{f['fleet_speedup_x']}x tokens/s, SLO "
-              f"{f['sweep']['paged_x2']['slo_attainment']:.0%} vs "
-              f"{f['sweep']['dense_x1']['slo_attainment']:.0%}")
     path = write_json(sections, smoke=args.smoke)
     print(f"[bench_serving] -> {path}")
     return sections

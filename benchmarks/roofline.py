@@ -5,9 +5,12 @@ Per (arch × shape × mesh) cell, derive the three terms from
 post-SPMD — multiplying back by chip count and dividing again per the
 assignment's formulas is an identity, noted in EXPERIMENTS.md):
 
-    compute_s    = HLO_FLOPs / (chips × 197 TFLOP/s bf16)
-    memory_s     = HLO_bytes / (chips × 819 GB/s HBM)
-    collective_s = collective_operand_bytes / (chips × 50 GB/s/link)
+    compute_s    = HLO_FLOPs / (chips × peak bf16 FLOP/s)
+    memory_s     = HLO_bytes / (chips × peak HBM bytes/s)
+    collective_s = collective_operand_bytes / (chips × link bytes/s)
+
+with the peaks of the record's ``device_kind`` (the chip the dry-run
+mesh models) from ``PEAKS``; a kind not in the table is an error.
 
 plus MODEL_FLOPS (6·N·D train / 2·N·D inference, N_active for MoE), the
 useful-compute ratio, the dominant term, and a what-would-move-it note.
@@ -17,9 +20,22 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # B/s / chip
-LINK_BW = 50e9            # B/s / link
+#: per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google
+#: Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+#: 1,600 Gbit/s chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak-rate row for ``device_kind``; raises on a kind the table
+    does not list instead of assuming another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 DRYRUN_DIR = Path(__file__).resolve().parents[1] / "experiments" / "dryrun"
 
@@ -50,10 +66,11 @@ def analyze(rec):
     coll_dev = rec["collectives"]["total_bytes"]
     link_dev = rec["collectives"].get("total_link_bytes", coll_dev)
 
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    collective_s = coll_dev / LINK_BW
-    collective_link_s = link_dev / LINK_BW
+    peak = peaks(rec.get("device_kind"))
+    compute_s = flops_dev / peak["flops"]
+    memory_s = bytes_dev / peak["hbm_bw"]
+    collective_s = coll_dev / peak["link_bw"]
+    collective_link_s = link_dev / peak["link_bw"]
 
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
@@ -71,7 +88,7 @@ def analyze(rec):
     bound_s = max(terms.values())
     # roofline fraction: useful model FLOPs per second at the bound vs peak
     ach_flops = model_flops / chips / bound_s if bound_s else 0.0
-    frac = ach_flops / PEAK_FLOPS
+    frac = ach_flops / peak["flops"]
 
     return {
         "arch": rec["arch"], "shape": shape, "mesh": rec["mesh"],

@@ -48,6 +48,8 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes, skip the slow app/roofline benches")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import bench_apps, bench_conventional, bench_dima
     from benchmarks import bench_serving, roofline

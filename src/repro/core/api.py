@@ -80,20 +80,16 @@ MODES = ("dp", "md")
 
 _DISPATCH_COUNT = [0]
 
-# trace_state_clean is a private jax.core re-export; resolve it once with
-# a fallback so a future jax that strips it degrades the *counter* (it
-# would also tick while tracing into an enclosing jit — harmless for the
-# post-warm-up smoke guard) instead of breaking every compute call
-_trace_state_clean = getattr(jax.core, "trace_state_clean", None)
-
 
 def _dispatch(thunk):
     """Run ``thunk`` (a zero-arg closure over one compiled-computation
-    launch), counting it only when executed for real — not while being
-    traced into an enclosing jit."""
-    if _trace_state_clean is None or _trace_state_clean():
+    launch), counting it only when executed for real: a launch traced
+    into an enclosing jit (or vmap) returns tracers, not arrays."""
+    out = thunk()
+    if not any(isinstance(x, jax.core.Tracer)
+               for x in jax.tree_util.tree_leaves(out)):
         _DISPATCH_COUNT[0] += 1
-    return thunk()
+    return out
 
 
 class count_dispatches:
@@ -1130,7 +1126,6 @@ class MultiBankBackend(DimaBackend):
         device path, codes bitwise equal to the host fused Pallas path
         (which stays the oracle; volts/trimmed to float-assembly
         tolerance)."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
         _check_mode(mode)
         pallas_inner = isinstance(self.inner, PallasBackend)
@@ -1183,13 +1178,13 @@ class MultiBankBackend(DimaBackend):
                                                          vrange, mode)
 
             n_out = 3 if has_trim else 2
-            self._jit[k] = jax.jit(shard_map(
+            self._jit[k] = jax.jit(jax.shard_map(
                 per_shard, mesh=self.mesh,
                 in_specs=(PartitionSpec("banks"), PartitionSpec(),
                           PartitionSpec(), PartitionSpec(),
                           PartitionSpec()),
                 out_specs=(PartitionSpec("banks"),) * n_out,
-                check_rep=False))
+                check_vma=False))
         return self._jit[k]
 
     def _mesh_call(self, kind, banked, q, mode, key, v_range, trim):

@@ -8,10 +8,9 @@ transfer-function + mismatch + noise math runs vectorized on (BM, 256)
 tiles in VMEM.  Noise is an explicit operand — kernels must be
 deterministic — and the jnp oracle is kernels/ref.py::dima_dp_ref.
 
-Grid: (B, M/BM) — the query batch on the first grid axis, stored-row
-blocks on the second, so a matmat is ONE kernel launch and a matvec is
-the B=1 special case (``dima_dp``).  The same grid layout serves each
-shard of the multi-bank backend: a bank is just a smaller M.  Lane
+Grid: (NB, B, M/BM) — banks, queries, stored-row blocks
+(kernels/_banked.py), so a banked matmat is ONE kernel launch, a
+single-bank matmat is NB=1 and a matvec is B=1 (``dima_dp``).  Lane
 layout: the 128 columns of one access cycle sit on the 128-lane axis;
 the two sub-range cycles stack on sublanes.
 """
@@ -21,14 +20,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import pipeline as pipeline_mod
 from repro.core.params import DimaParams
-from repro.kernels._interpret import resolve_interpret
-
-BM = 128
+from repro.kernels._banked import BM, banked_call
 
 
 def _make_kernel(p: DimaParams, trim: bool = False):
@@ -66,8 +61,7 @@ def _make_kernel(p: DimaParams, trim: bool = False):
         vlr = jnp.mean(rail_l, axis=2) + cn[:, :, 1]
         v = (16.0 * jnp.mean(vmr, axis=1) + jnp.mean(vlr, axis=1)) / 17.0
 
-        # ADC (8-b single-slope); reshape to the block shape so the same
-        # body serves the (B, M/BM) and bank-leading (NB, B, M/BM) grids
+        # ADC (8-b single-slope); codes land in the (1, BM) output block
         vr = vr_ref[...]
         full = float(2 ** p.adc_bits - 1)
         x = (v - vr[0, 0]) / jnp.maximum(vr[0, 1] - vr[0, 0], 1e-9)
@@ -91,116 +85,40 @@ def _make_kernel(p: DimaParams, trim: bool = False):
 
 
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
-def dima_dp_batch(d, qs, col_gain, cap_eps, mult_gain, mult_off, read_noise,
-                  cblp_noise, v_range, ep=None, *,
-                  params: DimaParams = DimaParams(), interpret=None):
-    """Query-batched grid: d (M,256) uint8; qs (B,256) uint8; chip arrays
-    (…,128); read_noise (B,M,2,128); cblp_noise (B,M,2,2); v_range (1,2).
-    Returns (codes (B,M) int32, volts (B,M) f32) — one kernel launch.
-
-    ``ep`` (B,4) f32 rows ``[c0, c1, c2, Σq_b]`` switch on the fused
-    calibration epilogue: a third output ``trimmed`` (B,M) f32 is
-    appended, computed in-kernel as ``pipeline.trim_epilogue``."""
-    M = d.shape[0]
-    B = qs.shape[0]
-    assert M % BM == 0, M
-    interpret = resolve_interpret(interpret)
-    grid = (B, M // BM)
-    trim = ep is not None
-    in_specs = [
-        pl.BlockSpec((BM, 256), lambda b, i: (i, 0)),
-        pl.BlockSpec((1, 256), lambda b, i: (b, 0)),
-        pl.BlockSpec((1, 128), lambda b, i: (0, 0)),
-        pl.BlockSpec((1, 128), lambda b, i: (0, 0)),
-        pl.BlockSpec((2, 128), lambda b, i: (0, 0)),
-        pl.BlockSpec((2, 128), lambda b, i: (0, 0)),
-        pl.BlockSpec((1, BM, 2, 128), lambda b, i: (b, i, 0, 0)),
-        pl.BlockSpec((1, BM, 2, 2), lambda b, i: (b, i, 0, 0)),
-        pl.BlockSpec((1, 2), lambda b, i: (0, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, BM), lambda b, i: (b, i)),
-        pl.BlockSpec((1, BM), lambda b, i: (b, i)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, M), jnp.int32),
-        jax.ShapeDtypeStruct((B, M), jnp.float32),
-    ]
-    operands = [d, qs, col_gain.reshape(1, 128), cap_eps.reshape(1, 128),
-                mult_gain, mult_off, read_noise, cblp_noise, v_range]
-    if trim:
-        in_specs.append(pl.BlockSpec((1, 4), lambda b, i: (b, 0)))
-        out_specs.append(pl.BlockSpec((1, BM), lambda b, i: (b, i)))
-        out_shape.append(jax.ShapeDtypeStruct((B, M), jnp.float32))
-        operands.append(ep)
-    return tuple(pl.pallas_call(
-        _make_kernel(params, trim),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands))
-
-
-@functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def dima_dp_bank_batch(d, qs, col_gain, cap_eps, mult_gain, mult_off,
                        read_noise, cblp_noise, v_range, ep=None, *,
                        params: DimaParams = DimaParams(), interpret=None):
     """Bank-leading grid: d (NB, M, 256) uint8 — one multibank shard per
-    leading index; qs (B, 256); read_noise (NB, B, M, 2, 128); cblp_noise
-    (NB, B, M, 2, 2); v_range (NB, 2) — one ADC window per bank (equal
-    rows ≡ the old shared window; distinct rows serve the bitserial
-    per-plane calibrated windows).  Returns (codes (NB, B, M) int32,
-    volts (NB, B, M) f32): the whole banked matmat is ONE kernel launch
-    over a (NB, B, M/BM) grid — per-block compute identical to
-    ``dima_dp_batch``, so results are bitwise equal to launching that
-    kernel once per bank with the corresponding noise slices.
+    leading index; qs (B, 256) uint8; chip arrays (…,128); read_noise
+    (NB, B, M, 2, 128); cblp_noise (NB, B, M, 2, 2); v_range (NB, 2) —
+    one ADC window per bank (equal rows ≡ one shared window; distinct
+    rows serve the bitserial per-plane calibrated windows).  Returns
+    (codes (NB, B, M) int32, volts (NB, B, M) f32) from ONE kernel
+    launch over a (NB, B, M/BM) grid.
 
-    ``ep`` (B,4) f32 rows ``[c0, c1, c2, Σq_b]`` append a fused-trim
-    third output (NB, B, M) f32 (see ``dima_dp_batch``)."""
-    NB, M = d.shape[0], d.shape[1]
-    B = qs.shape[0]
-    assert M % BM == 0, M
-    interpret = resolve_interpret(interpret)
-    grid = (NB, B, M // BM)
+    ``ep`` (B,4) f32 rows ``[c0, c1, c2, Σq_b]`` switch on the fused
+    calibration epilogue: a third output ``trimmed`` (NB, B, M) f32 is
+    appended, computed in-kernel as ``pipeline.trim_epilogue``."""
     trim = ep is not None
-    in_specs = [
-        pl.BlockSpec((1, BM, 256), lambda nb, b, i: (nb, i, 0)),
-        pl.BlockSpec((1, 256), lambda nb, b, i: (b, 0)),
-        pl.BlockSpec((1, 128), lambda nb, b, i: (0, 0)),
-        pl.BlockSpec((1, 128), lambda nb, b, i: (0, 0)),
-        pl.BlockSpec((2, 128), lambda nb, b, i: (0, 0)),
-        pl.BlockSpec((2, 128), lambda nb, b, i: (0, 0)),
-        pl.BlockSpec((1, 1, BM, 2, 128),
-                     lambda nb, b, i: (nb, b, i, 0, 0)),
-        pl.BlockSpec((1, 1, BM, 2, 2),
-                     lambda nb, b, i: (nb, b, i, 0, 0)),
-        pl.BlockSpec((1, 2), lambda nb, b, i: (nb, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, BM), lambda nb, b, i: (nb, b, i)),
-        pl.BlockSpec((1, 1, BM), lambda nb, b, i: (nb, b, i)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((NB, B, M), jnp.int32),
-        jax.ShapeDtypeStruct((NB, B, M), jnp.float32),
-    ]
-    operands = [d, qs, col_gain.reshape(1, 128), cap_eps.reshape(1, 128),
-                mult_gain, mult_off, read_noise, cblp_noise, v_range]
-    if trim:
-        in_specs.append(pl.BlockSpec((1, 4), lambda nb, b, i: (b, 0)))
-        out_specs.append(pl.BlockSpec((1, 1, BM), lambda nb, b, i: (nb, b, i)))
-        out_shape.append(jax.ShapeDtypeStruct((NB, B, M), jnp.float32))
-        operands.append(ep)
-    return tuple(pl.pallas_call(
-        _make_kernel(params, trim),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands))
+    consts = [col_gain.reshape(1, 128), cap_eps.reshape(1, 128),
+              mult_gain, mult_off]
+    return banked_call(_make_kernel(params, trim), d, qs, consts,
+                       [read_noise, cblp_noise], v_range, ep,
+                       3 if trim else 2, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("params", "interpret"))
+def dima_dp_batch(d, qs, col_gain, cap_eps, mult_gain, mult_off, read_noise,
+                  cblp_noise, v_range, ep=None, *,
+                  params: DimaParams = DimaParams(), interpret=None):
+    """One bank: d (M,256); qs (B,256); read_noise (B,M,2,128);
+    cblp_noise (B,M,2,2); v_range (1,2).  Returns (codes (B,M), volts
+    (B,M)[, trimmed (B,M)]) — ``dima_dp_bank_batch`` at NB=1."""
+    out = dima_dp_bank_batch(
+        d[None], qs, col_gain, cap_eps, mult_gain, mult_off,
+        read_noise[None], cblp_noise[None], v_range, ep, params=params,
+        interpret=interpret)
+    return tuple(o[0] for o in out)
 
 
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))

@@ -4,8 +4,8 @@ Dual-rail functional read — BL develops f(D + P̄), BLB the complementary
 f(D̄ + P) — comparator + mux pick the deeper swing, CBLP averages, ADC
 converts.  Oracle: kernels/ref.py::dima_md_ref.
 
-Grid: (B, M/BM) like dima_dp.py — matmat in one launch, matvec = B=1,
-and each multi-bank shard reuses the same layout with a smaller M.
+Grid: (NB, B, M/BM) like dima_dp.py (kernels/_banked.py) — a banked
+matmat in one launch, a single bank is NB=1 and a matvec is B=1.
 """
 from __future__ import annotations
 
@@ -13,13 +13,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core import pipeline as pipeline_mod
 from repro.core.params import DimaParams
-from repro.kernels._interpret import resolve_interpret
-
-BM = 128
+from repro.kernels._banked import BM, banked_call
 
 
 def _transfer(c, p, beta):
@@ -59,8 +56,6 @@ def _make_kernel(p: DimaParams, trim: bool = False):
         v = jnp.mean(v_abs, axis=2) + cn_ref[...].reshape(BM, 2)
         v = jnp.mean(v, axis=1)
 
-        # reshape to the block shape so the same body serves the
-        # (B, M/BM) and bank-leading (NB, B, M/BM) grids
         vr = vr_ref[...]
         full = float(2 ** p.adc_bits - 1)
         x = (v - vr[0, 0]) / jnp.maximum(vr[0, 1] - vr[0, 0], 1e-9)
@@ -81,107 +76,34 @@ def _make_kernel(p: DimaParams, trim: bool = False):
 
 
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
-def dima_md_batch(d, qs, col_gain, cap_eps, cmp_noise, read_noise,
-                  read_noise_b, cblp_noise, v_range, ep=None, *,
-                  params: DimaParams = DimaParams(), interpret=None):
-    """d (M,256) uint8; qs (B,256); cmp/read noise (B,M,2,128); cblp
-    (B,M,2); v_range (1,2).  Returns (codes (B,M), volts (B,M)) in one
-    kernel launch; ``ep`` (B,4) appends a fused-trim third output (see
-    ``dima_dp.dima_dp_batch``)."""
-    M = d.shape[0]
-    B = qs.shape[0]
-    assert M % BM == 0, M
-    interpret = resolve_interpret(interpret)
-    trim = ep is not None
-    in_specs = [
-        pl.BlockSpec((BM, 256), lambda b, i: (i, 0)),
-        pl.BlockSpec((1, 256), lambda b, i: (b, 0)),
-        pl.BlockSpec((1, 128), lambda b, i: (0, 0)),
-        pl.BlockSpec((1, 128), lambda b, i: (0, 0)),
-        pl.BlockSpec((1, BM, 2, 128), lambda b, i: (b, i, 0, 0)),
-        pl.BlockSpec((1, BM, 2, 128), lambda b, i: (b, i, 0, 0)),
-        pl.BlockSpec((1, BM, 2, 128), lambda b, i: (b, i, 0, 0)),
-        pl.BlockSpec((1, BM, 2), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, 2), lambda b, i: (0, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, BM), lambda b, i: (b, i)),
-        pl.BlockSpec((1, BM), lambda b, i: (b, i)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, M), jnp.int32),
-        jax.ShapeDtypeStruct((B, M), jnp.float32),
-    ]
-    operands = [d, qs, col_gain.reshape(1, 128), cap_eps.reshape(1, 128),
-                cmp_noise, read_noise, read_noise_b, cblp_noise, v_range]
-    if trim:
-        in_specs.append(pl.BlockSpec((1, 4), lambda b, i: (b, 0)))
-        out_specs.append(pl.BlockSpec((1, BM), lambda b, i: (b, i)))
-        out_shape.append(jax.ShapeDtypeStruct((B, M), jnp.float32))
-        operands.append(ep)
-    return tuple(pl.pallas_call(
-        _make_kernel(params, trim),
-        grid=(B, M // BM),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands))
-
-
-@functools.partial(jax.jit, static_argnames=("params", "interpret"))
 def dima_md_bank_batch(d, qs, col_gain, cap_eps, cmp_noise, read_noise,
                        read_noise_b, cblp_noise, v_range, ep=None, *,
                        params: DimaParams = DimaParams(), interpret=None):
     """Bank-leading grid: d (NB, M, 256) — one multibank shard per
     leading index; qs (B, 256); cmp/read noise (NB, B, M, 2, 128); cblp
     (NB, B, M, 2); v_range (NB, 2) — one ADC window per bank.  Returns
-    (codes (NB, B, M), volts (NB, B, M)): the banked matmat is ONE
-    kernel launch over a (NB, B, M/BM) grid, per-block compute identical
-    to ``dima_md_batch``; ``ep`` (B,4) appends a fused-trim third
-    output."""
-    NB, M = d.shape[0], d.shape[1]
-    B = qs.shape[0]
-    assert M % BM == 0, M
-    interpret = resolve_interpret(interpret)
+    (codes (NB, B, M), volts (NB, B, M)) from ONE kernel launch over a
+    (NB, B, M/BM) grid; ``ep`` (B,4) appends a fused-trim third output
+    (see ``dima_dp.dima_dp_bank_batch``)."""
     trim = ep is not None
-    in_specs = [
-        pl.BlockSpec((1, BM, 256), lambda nb, b, i: (nb, i, 0)),
-        pl.BlockSpec((1, 256), lambda nb, b, i: (b, 0)),
-        pl.BlockSpec((1, 128), lambda nb, b, i: (0, 0)),
-        pl.BlockSpec((1, 128), lambda nb, b, i: (0, 0)),
-        pl.BlockSpec((1, 1, BM, 2, 128),
-                     lambda nb, b, i: (nb, b, i, 0, 0)),
-        pl.BlockSpec((1, 1, BM, 2, 128),
-                     lambda nb, b, i: (nb, b, i, 0, 0)),
-        pl.BlockSpec((1, 1, BM, 2, 128),
-                     lambda nb, b, i: (nb, b, i, 0, 0)),
-        pl.BlockSpec((1, 1, BM, 2), lambda nb, b, i: (nb, b, i, 0)),
-        pl.BlockSpec((1, 2), lambda nb, b, i: (nb, 0)),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, BM), lambda nb, b, i: (nb, b, i)),
-        pl.BlockSpec((1, 1, BM), lambda nb, b, i: (nb, b, i)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((NB, B, M), jnp.int32),
-        jax.ShapeDtypeStruct((NB, B, M), jnp.float32),
-    ]
-    operands = [d, qs, col_gain.reshape(1, 128), cap_eps.reshape(1, 128),
-                cmp_noise, read_noise, read_noise_b, cblp_noise, v_range]
-    if trim:
-        in_specs.append(pl.BlockSpec((1, 4), lambda nb, b, i: (b, 0)))
-        out_specs.append(pl.BlockSpec((1, 1, BM), lambda nb, b, i: (nb, b, i)))
-        out_shape.append(jax.ShapeDtypeStruct((NB, B, M), jnp.float32))
-        operands.append(ep)
-    return tuple(pl.pallas_call(
-        _make_kernel(params, trim),
-        grid=(NB, B, M // BM),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands))
+    consts = [col_gain.reshape(1, 128), cap_eps.reshape(1, 128)]
+    return banked_call(_make_kernel(params, trim), d, qs, consts,
+                       [cmp_noise, read_noise, read_noise_b, cblp_noise],
+                       v_range, ep, 3 if trim else 2, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("params", "interpret"))
+def dima_md_batch(d, qs, col_gain, cap_eps, cmp_noise, read_noise,
+                  read_noise_b, cblp_noise, v_range, ep=None, *,
+                  params: DimaParams = DimaParams(), interpret=None):
+    """One bank: d (M,256); qs (B,256); cmp/read noise (B,M,2,128); cblp
+    (B,M,2); v_range (1,2).  Returns (codes (B,M), volts (B,M)[, trimmed
+    (B,M)]) — ``dima_md_bank_batch`` at NB=1."""
+    out = dima_md_bank_batch(
+        d[None], qs, col_gain, cap_eps, cmp_noise[None], read_noise[None],
+        read_noise_b[None], cblp_noise[None], v_range, ep, params=params,
+        interpret=interpret)
+    return tuple(o[0] for o in out)
 
 
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))

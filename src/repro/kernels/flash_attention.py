@@ -17,6 +17,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._interpret import resolve_interpret
+
 BQ, BK = 128, 128
 NEG = -0.7 * float(np.finfo(np.float32).max)
 
@@ -63,8 +65,7 @@ def flash_attention(q, k, v, *, interpret=None):
     """q,k,v: (B, S, dh), causal. B folds batch×heads. S % 128 == 0."""
     B, S, dh = q.shape
     assert S % BQ == 0 and S % BK == 0, S
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     scale = 1.0 / np.sqrt(dh)
     grid = (B, S // BQ, S // BK)
     return pl.pallas_call(

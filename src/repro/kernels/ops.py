@@ -152,7 +152,7 @@ def dima_dp_matmat(d, qs, p: DimaParams = DimaParams(), chip=None, key=None,
                    v_range=None, interpret=None, trim=None):
     """Query-batched DP: d (M,256) uint8 rows vs queries qs (B,256).
     Returns (codes (B,M), volts (B,M)) from ONE kernel launch — the grid
-    is (B, M/128), so the per-query Python loop disappears.  ``trim``
+    is (1, B, M/128), so the per-query Python loop disappears.  ``trim``
     appends fused trimmed scores (B,M)."""
     M = d.shape[0]
     B = qs.shape[0]

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels._interpret import resolve_interpret
+
 BM, BN, BK = 128, 128, 128
 
 
@@ -55,8 +57,7 @@ def subrange_matmul(x_q, x_scale, w_q, w_scale, *, interpret=None):
     M, K = x_q.shape
     N = w_q.shape[1]
     assert M % BM == 0 and K % BK == 0 and N % BN == 0, (M, K, N)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     grid = (M // BM, N // BN, K // BK)
     return pl.pallas_call(
         _kernel,

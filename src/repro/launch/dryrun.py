@@ -36,6 +36,9 @@ from repro.models import LM
 from repro.optim import adamw_init
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
+#: the chip the production meshes model (a 16x16 v5e pod, two for
+#: multi-pod); benchmarks/roofline.py reads its peaks by this kind
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8, "f32": 4, "s32": 4, "u32": 4,
@@ -268,6 +271,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     rec = {
         "arch": arch_name, "shape": shape_name, "mesh": mesh_name,
         "quant": quant, "variant": variant, "ok": False,
+        "device_kind": TARGET_DEVICE_KIND,
     }
     t0 = time.time()
     try:
@@ -279,8 +283,6 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
         rec["compile_s"] = round(time.time() - t1, 1)
 
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):                  # older jax: list of dicts
-            ca = ca[0] if ca else {}
         rec["cost_analysis"] = {
             k: float(v) for k, v in ca.items()
             if isinstance(v, (int, float)) and k in
@@ -347,6 +349,8 @@ def main():
     ap.add_argument("--tag", default="", help="suffix for experiment variants")
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--list", action="store_true")
+    ap.add_argument("--out-dir", type=Path, default=OUT_DIR,
+                    help="where the per-cell JSON records are written")
     args = ap.parse_args()
 
     if args.list:
@@ -367,13 +371,14 @@ def main():
     for a, s, mp in todo:
         mesh_name = "pod2x16x16" if mp else "pod16x16"
         suffix = f"__{args.tag}" if args.tag else ""
-        fn = OUT_DIR / f"{a}__{s}__{mesh_name}{suffix}.json"
+        fn = args.out_dir / f"{a}__{s}__{mesh_name}{suffix}.json"
         if fn.exists() and not args.force:
             rec = json.loads(fn.read_text())
             if rec.get("ok"):
                 print(f"[dryrun] {a} x {s} x {mesh_name}: cached OK")
                 continue
-        rec = run_cell(a, s, mp, quant=args.quant, tag=args.tag,
+        rec = run_cell(a, s, mp, quant=args.quant, out_dir=args.out_dir,
+                       tag=args.tag,
                        variant=args.variant, remat=args.remat, kv=args.kv)
         failures += 0 if rec["ok"] else 1
     if failures:

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,7 +20,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(shape))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}; have {len(devices)}. "
@@ -44,4 +45,5 @@ def make_elastic_mesh(n_model: int = 0) -> Mesh:
         n_model = 1
         while n_model < 16 and n % (n_model * 2) == 0:
             n_model *= 2
-    return jax.make_mesh((n // n_model, n_model), ("data", "model"))
+    return jax.make_mesh((n // n_model, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

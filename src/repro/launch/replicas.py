@@ -5,10 +5,12 @@ behind one shared FIFO, under open-loop (Poisson wall-clock) load.
         --rate 20 --requests 48            # or just: --smoke
 
 Each replica is a separate *process* (spawn, not fork: the per-replica
-environment — ``XLA_FLAGS=--xla_force_host_platform_device_count=1``,
-``TF_CPP_MIN_LOG_LEVEL``, tcmalloc large-alloc silencing, per SNIPPETS
-snippet 1 — must be set before its jax initializes) running its own
-paged-KV ``ServeEngine`` over identically-initialized params.  A single
+environment must be set before its jax initializes) running its own
+paged-KV ``ServeEngine`` over identically-initialized params.  On a TPU
+host each replica is pinned to its own chip (``replica_env``), so a
+fleet needs as many visible chips as replicas, and the parent process
+stays off JAX until its replicas have exited (a process that has
+initialized JAX holds every chip it sees).  A single
 ``multiprocessing.Queue`` is the fleet's FIFO: replicas race to pull,
 so a hot replica with free blocks naturally takes more of the load and
 no request is ever assigned to a stalled engine.  ``--affinity prompt``
@@ -34,10 +36,13 @@ for the committed BENCH_serving.json replica sweep.
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import multiprocessing as mp
+import multiprocessing.connection as mp_connection
 import os
 import queue as queue_mod
+import socket
 import sys
 import time
 import zlib
@@ -48,17 +53,62 @@ READY_TIMEOUT_S = 600.0     # per-replica model build + warm-up compile
 POLL_S = 0.002              # idle worker poll interval
 
 
-def replica_env(idx: int) -> dict:
-    """Per-replica process environment (SNIPPETS.md snippet 1): pin one
-    XLA host device per replica, silence TF/tcmalloc chatter.  tcmalloc
-    itself is LD_PRELOADed by the operator when present — a missing lib
-    must not kill the worker, so we only set its report threshold."""
-    return {
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
+#: PCI device ids of TPU chips (Google vendor id 0x1ae0); other Google
+#: PCI functions, such as the gVNIC network card, are not chips
+_TPU_PCI_IDS = {"0x0027", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"}
+
+
+def visible_tpu_chips() -> int:
+    """TPU chips this host exposes, counted on the PCI bus (or, where
+    /sys hides it, as /dev/accel* or /dev/vfio device nodes) — without
+    initializing JAX, which would claim them for this process."""
+    n = 0
+    for dev in glob.glob("/sys/bus/pci/devices/*"):
+        try:
+            with open(os.path.join(dev, "vendor")) as f:
+                vendor = f.read().strip()
+            with open(os.path.join(dev, "device")) as f:
+                device = f.read().strip()
+        except OSError:
+            continue
+        n += vendor == "0x1ae0" and device in _TPU_PCI_IDS
+    return max(n, len(glob.glob("/dev/accel[0-9]*")),
+               len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def tpu_fleet() -> bool:
+    """True when replicas will run on TPU chips: the host has chips and
+    ``$JAX_PLATFORMS`` (if set) allows the TPU."""
+    plats = os.environ.get("JAX_PLATFORMS", "")
+    return visible_tpu_chips() > 0 and (
+        not plats or "tpu" in plats.split(","))
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("localhost", 0))
+        return s.getsockname()[1]
+
+
+def replica_env(idx: int, port: int = None) -> dict:
+    """Per-replica process environment: silence TF/tcmalloc chatter
+    (tcmalloc itself is LD_PRELOADed by the operator when present, so
+    only its report threshold is set) and, given the ``port`` of its
+    own single-chip slice, pin the replica to TPU chip ``idx``."""
+    env = {
         "TF_CPP_MIN_LOG_LEVEL": "4",
         "TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD": "60000000000",
         "DIMA_REPLICA": str(idx),
     }
+    if port is not None:
+        env.update({
+            "TPU_VISIBLE_CHIPS": str(idx),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        })
+    return env
 
 
 def make_shared_trace(n_requests: int, *, seed=0, vocab=256, n_templates=3,
@@ -114,7 +164,7 @@ def _done_msg(idx: int, r) -> dict:
             "energy_pj": r.energy_pj}
 
 
-def _worker(idx: int, spec: dict, req_q, res_q, stop_evt):
+def _worker(idx: int, spec: dict, req_q, res_conn, stop_evt, port=None):
     """Replica main: warm up, signal ready, then race the shared FIFO —
     pull whatever is visible, advance the engine one lockstep tick,
     repeat.  Runs until the parent sets ``stop_evt`` (it only does so
@@ -127,16 +177,21 @@ def _worker(idx: int, spec: dict, req_q, res_q, stop_evt):
     drains the seated slots to completion, hands queued-but-unseated
     rids back via ``requeue`` messages, and still emits the final stats
     record."""
-    os.environ.update(replica_env(idx))
+    os.environ.update(replica_env(idx, port))
+    import jax
+
     from repro.distributed.fault_tolerance import PreemptionGuard
     from repro.inference import Request
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     _, _, eng = _build_engine(spec)
+    dev = jax.devices()[0]
     warm = Request(rid=-1, prompt=np.arange(1, 5, dtype=np.int32), max_new=3)
     eng.submit(warm)
     eng.run()
     warm_stats = dict(eng.stats)
-    res_q.put({"kind": "ready", "replica": idx})
+    res_conn.send({"kind": "ready", "replica": idx})
 
     busy_s = 0.0
     preempted = False
@@ -148,11 +203,11 @@ def _worker(idx: int, spec: dict, req_q, res_q, stop_evt):
                 t0 = time.time()
                 for r in eng.drain():            # finish in-flight slots
                     if r.rid >= 0:
-                        res_q.put(_done_msg(idx, r))
+                        res_conn.send(_done_msg(idx, r))
                 busy_s += time.time() - t0
                 for r in eng.queue:              # unseated: hand back
                     if r.rid >= 0:
-                        res_q.put({"kind": "requeue", "replica": idx,
+                        res_conn.send({"kind": "requeue", "replica": idx,
                                    "rid": r.rid})
                 break
             pulled = False
@@ -163,7 +218,7 @@ def _worker(idx: int, spec: dict, req_q, res_q, stop_evt):
                     rid, prompt, mx, t_sub = req_q.get_nowait()
                 except queue_mod.Empty:
                     break
-                res_q.put({"kind": "claim", "replica": idx, "rid": rid})
+                res_conn.send({"kind": "claim", "replica": idx, "rid": rid})
                 eng.submit(Request(rid=rid,
                                    prompt=np.asarray(prompt, np.int32),
                                    max_new=mx, submitted_at=t_sub))
@@ -173,14 +228,15 @@ def _worker(idx: int, spec: dict, req_q, res_q, stop_evt):
                 for r in eng.step():
                     if r.rid < 0:
                         continue
-                    res_q.put(_done_msg(idx, r))
+                    res_conn.send(_done_msg(idx, r))
                 busy_s += time.time() - t0
             elif stop_evt.is_set():
                 break
             elif not pulled:
                 time.sleep(POLL_S)
     wall = max(time.time() - t_ready, 1e-9)
-    res_q.put({"kind": "stats", "replica": idx, "preempted": preempted,
+    res_conn.send({"kind": "stats", "replica": idx, "preempted": preempted,
+               "platform": dev.platform, "device_kind": dev.device_kind,
                "utilization": round(busy_s / wall, 4),
                "busy_s": round(busy_s, 4), "wall_s": round(wall, 4),
                "jit_traces": dict(eng.jit_traces),
@@ -250,8 +306,18 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
         n_requests, seed=seed)
     n_requests = len(prompts)
 
+    ports = [None] * n_replicas
+    if tpu_fleet():
+        chips = visible_tpu_chips()
+        if n_replicas > chips:
+            raise ValueError(f"{n_replicas} replicas need one TPU chip each; "
+                             f"this host has {chips}")
+        ports = [_free_port() for _ in range(n_replicas)]
+
     ctx = mp.get_context(mp_ctx)
-    res_q = ctx.Queue()
+    # one result pipe per worker process: a worker killed mid-send can
+    # only break its own pipe, never a lock its peers write through
+    conns = []
     stop_evt = ctx.Event()
     if affinity == "prompt":
         req_qs = [ctx.Queue() for _ in range(n_replicas)]
@@ -265,9 +331,13 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
                          f"got {affinity!r}")
 
     def spawn(i):
-        p = ctx.Process(target=_worker, args=(i, spec, req_qs[i], res_q,
-                                              stop_evt), daemon=True)
+        r, w = ctx.Pipe(duplex=False)
+        p = ctx.Process(target=_worker, args=(i, spec, req_qs[i], w,
+                                              stop_evt, ports[i]),
+                        daemon=True)
         p.start()
+        w.close()                         # the worker holds the write end
+        conns.append(r)
         return p
 
     proc_by_idx = {i: spawn(i) for i in range(n_replicas)}
@@ -341,6 +411,11 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
         for i, p in list(proc_by_idx.items()):
             if i in dead or p.is_alive() or i in stats:
                 continue
+            if i not in ready:
+                # a replica that cannot start is a broken deployment
+                # (no chip, bad environment), not a crash to survive
+                raise RuntimeError(f"replica {i} exited during start-up "
+                                   f"(exit code {p.exitcode})")
             dead.add(i)
             counters["replicas_crashed"] += 1
             reroute(i)
@@ -356,23 +431,32 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
             if p is not None and p.is_alive():
                 p.kill()
 
-    def pump(timeout=READY_TIMEOUT_S):
-        """Receive one message, polling worker liveness while waiting —
-        a crash mid-run surfaces here as a reroute, not a hang."""
-        deadline = time.time() + timeout
-        while True:
+    def receive(timeout):
+        """Handle every message that arrives within ``timeout``; a
+        closed pipe (its worker exited) is dropped.  Returns the count."""
+        n = 0
+        for c in mp_connection.wait(conns, timeout):
             try:
-                msg = res_q.get(timeout=0.05)
-            except queue_mod.Empty:
-                check_crashes()
-                if time.time() > deadline:
-                    raise RuntimeError(
-                        f"fleet stalled: no worker messages for "
-                        f"{timeout:.0f}s ({len(results)}/{n_requests} done, "
-                        f"crashed={sorted(dead)})")
+                msg = c.recv()
+            except EOFError:
+                conns.remove(c)
                 continue
             handle(msg)
-            return msg
+            n += 1
+        return n
+
+    def pump(timeout=READY_TIMEOUT_S):
+        """Receive at least one message, polling worker liveness while
+        waiting — a crash mid-run surfaces here as a reroute, not a
+        hang."""
+        deadline = time.time() + timeout
+        while not receive(0.05):
+            check_crashes()
+            if time.time() > deadline:
+                raise RuntimeError(
+                    f"fleet stalled: no worker messages for "
+                    f"{timeout:.0f}s ({len(results)}/{n_requests} done, "
+                    f"crashed={sorted(dead)})")
 
     t0 = time.time()
     try:
@@ -398,12 +482,8 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
                 if lag <= 0:
                     break
                 time.sleep(min(lag, 0.005))
-                while True:                   # keep draining while pacing
-                    try:
-                        msg = res_q.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    handle(msg)
+                while receive(0):             # keep draining while pacing
+                    pass
                 check_crashes()
                 maybe_kill()
             submit_t[i] = time.time()
@@ -415,13 +495,14 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
         stop_evt.set()
         while any(i not in stats for i in proc_by_idx if i not in dead):
             pump()
-        for p in proc_by_idx.values():
-            p.join(timeout=60)
     finally:
         stop_evt.set()
         for p in proc_by_idx.values():
-            if p.is_alive():
-                p.terminate()
+            p.join(timeout=60)
+        for p in proc_by_idx.values():
+            if p.is_alive():             # never exit holding a chip
+                p.kill()
+                p.join()
 
     lat = np.array([results[i]["done_at"] - results[i]["submitted_at"]
                     for i in range(n_requests)])
@@ -438,6 +519,8 @@ def run_fleet(*, n_replicas=2, rate_rps=20.0, n_requests=48, arch="gemma3-1b",
             "jit_traces": s["jit_traces"], "engine": s["engine"]}
     rec = {
         "replicas": n_replicas, "kv": kv,
+        "platform": stats[min(stats)]["platform"] if stats else None,
+        "device_kind": stats[min(stats)]["device_kind"] if stats else None,
         "dispatch": affinity or "fifo",
         "rate_rps": round(float(rate_rps), 3), "requests": n_requests,
         "tokens": tokens, "wall_s": round(wall, 4),

@@ -53,6 +53,7 @@ from repro.core.params import DimaParams
 from repro.distributed.fault_tolerance import PreemptionGuard
 from repro.distributed.sharding import ShardCtx
 from repro.inference import Request, ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM
 from repro.quant import DimaNoiseModel, quantize_params
 
@@ -172,6 +173,7 @@ def main(argv=None):
         ap.error("--analog-lm requires 8-bit records (--quant dima)")
     if args.analog_lm:
         args.quant = "dima"
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -4,7 +4,7 @@ identity over random shapes/bit-widths; bitserial B=8 == digital at
 zero noise over random data and ADC windows)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import dima
 from repro.core.params import DimaParams
@@ -71,7 +71,7 @@ def test_signed_planes_compose():
 
 
 # ---------------------------------------------------------------------------
-# property-based (skipped cleanly when hypothesis is absent)
+# property-based
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=30, deadline=None)
@@ -112,8 +112,7 @@ def test_bitserial_b8_equals_digital_zero_noise(seed, m, n, custom_range):
     np.testing.assert_array_equal(np.asarray(a.volts), np.asarray(b.volts))
 
 
-# canary: records whether property bodies actually execute, so the shim
-# contract ("run iff hypothesis is installed") is itself under test
+# canary: records whether property bodies actually execute
 _RUNS = []
 
 
@@ -125,8 +124,5 @@ def test_property_canary(x):
 
 def test_canary_ran_iff_hypothesis_installed():
     """Relies on pytest's file-order execution: the canary above has
-    already run (or been skipped) by the time this asserts."""
-    if HAVE_HYPOTHESIS:
-        assert _RUNS, "hypothesis installed but property body never ran"
-    else:
-        assert not _RUNS, "shim executed a property body without hypothesis"
+    already run by the time this asserts."""
+    assert _RUNS, "hypothesis property body never ran"

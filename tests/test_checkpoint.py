@@ -68,13 +68,15 @@ import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import Checkpointer
 
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 w = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 w_a = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
 ck = Checkpointer(r'{tmp_path}')
 ck.save(1, {{"w": w_a}})
 
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sh_b = {{"w": NamedSharding(mesh_b, P("data", "model"))}}
 restored, step = ck.restore(
     {{"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}}, shardings=sh_b)

@@ -230,6 +230,19 @@ def test_chunked_dot_fused_matches_loop():
     assert c.n == 1                  # one dispatch, not one per chunk
 
 
+def test_count_dispatches_skips_launches_traced_into_a_jit():
+    """On its first call ``chunked_dot`` traces the backend's own jitted
+    dot into its jit: those inner launches return tracers and are not
+    dispatches; only the outer launch counts."""
+    r = np.random.default_rng(5)
+    w = jnp.asarray(r.integers(0, 256, (506,)))
+    X = jnp.asarray(r.integers(0, 256, (10, 506)))
+    be = dima.get_backend("reference", P)        # nothing traced yet
+    with dima.count_dispatches() as c:
+        dima.chunked_dot(be, w[None, :], X, key=KEY)
+    assert c.n == 1
+
+
 def test_stable_crossover_rule_tolerates_non_monotonic_timings():
     """The persisted auto_crossover_rows rule (docs/benchmarks.md): an
     isolated noisy loss above the threshold doesn't void the

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.distributed.compression import init_error_state, quantize_leaf
 from repro.distributed.fault_tolerance import StepWatchdog
@@ -22,7 +22,8 @@ from repro.configs import get_arch, reduced
 from repro.distributed.sharding import ShardCtx, param_shardings
 from repro.models import LM
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardCtx(mesh)
 for name in ("yi-34b", "phi3.5-moe-42b-a6.6b", "xlstm-1.3b",
              "recurrentgemma-2b"):
@@ -62,7 +63,8 @@ from repro.launch.steps import make_train_step
 from repro.models import LM
 from repro.optim import adamw_init
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardCtx(mesh)
 cfg = reduced(get_arch("gemma3-1b"))
 run = RunConfig(total_steps=4, warmup_steps=1)
@@ -113,11 +115,11 @@ def test_compressed_psum_converges(devices8):
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed.compression import (compressed_cross_pod_psum,
                                            init_error_state)
 
-mesh = jax.make_mesh((4, 2), ("pod", "data"))
+mesh = jax.make_mesh((4, 2), ("pod", "data"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 G = jax.random.normal(jax.random.PRNGKey(0), (4, 64))  # per-pod grads
 
 def step(g_local, err):
@@ -125,8 +127,8 @@ def step(g_local, err):
         (g_local,), (err,), axis_name="pod")
     return mean_g, new_err
 
-f = shard_map(step, mesh=mesh, in_specs=(P("pod"), P("pod")),
-              out_specs=(P("pod"), P("pod")))
+f = jax.shard_map(step, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                  out_specs=(P("pod"), P("pod")))
 err = jnp.zeros((4, 64))
 exact = jnp.mean(G, axis=0)
 accum_c = jnp.zeros((64,))

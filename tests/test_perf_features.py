@@ -64,9 +64,7 @@ def test_hlo_cost_counts_loop_trips():
     per_iter = 2 * 256 ** 3
     for K in (2, 8):
         c = jax.jit(make(K)).lower(sds, sds).compile()
-        cost = c.cost_analysis()          # list-of-dicts on older jax
-        if isinstance(cost, list):
-            cost = cost[0] if cost else {}
+        cost = c.cost_analysis()
         xla = cost["flops"]
         ours = analyze_hlo(c.as_text())["flops"]
         assert abs(xla - per_iter) / per_iter < 0.01      # XLA: once
@@ -111,7 +109,8 @@ from repro.launch.steps import make_train_step
 from repro.models import LM
 from repro.optim import adamw_init
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardCtx(mesh, variant="wg_ffn")
 cfg = reduced(get_arch("yi-34b"))
 run = RunConfig(total_steps=2, warmup_steps=1)

@@ -31,10 +31,28 @@ def test_shared_trace_is_deterministic_and_template_heavy():
 
 
 def test_replica_env_pins_one_host_device():
+    """Off the TPU a replica keeps the parent's devices; given its slice
+    port it is pinned to TPU chip ``idx`` alone."""
     env = replicas.replica_env(3)
-    assert "--xla_force_host_platform_device_count=1" in env["XLA_FLAGS"]
+    assert "XLA_FLAGS" not in env and "TPU_VISIBLE_CHIPS" not in env
     assert env["DIMA_REPLICA"] == "3"
     assert env["TF_CPP_MIN_LOG_LEVEL"] == "4"
+    env = replicas.replica_env(3, port=8479)
+    assert env["TPU_VISIBLE_CHIPS"] == "3"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"] == "localhost:8479"
+    assert "ALLOW_MULTIPLE_LIBTPU_LOAD" not in env
+
+
+def test_fleet_refuses_more_replicas_than_chips(monkeypatch):
+    """On a TPU host every replica needs a chip of its own: the fleet is
+    refused before any process starts."""
+    monkeypatch.setattr(replicas, "visible_tpu_chips", lambda: 2)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert replicas.tpu_fleet()
+    with pytest.raises(ValueError, match="3 replicas need one TPU chip"):
+        replicas.run_fleet(n_replicas=3, n_requests=2)
 
 
 @pytest.mark.slow

@@ -59,16 +59,20 @@ def test_serve_dima_quant():
 
 
 @pytest.mark.slow
-def test_dryrun_cell_compiles():
+def test_dryrun_cell_compiles(tmp_path):
     """One full-size dry-run cell end-to-end in a subprocess (512 devices)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "gemma3-1b",
-         "--shape", "decode_32k", "--multi-pod", "--force"],
+         "--shape", "decode_32k", "--multi-pod", "--force",
+         "--out-dir", str(tmp_path)],
         env=env, capture_output=True, text=True, timeout=560, cwd=ROOT)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "OK" in out.stdout
+    rec = json.loads((tmp_path / "gemma3-1b__decode_32k__pod2x16x16.json")
+                     .read_text())
+    assert rec["ok"], rec.get("error")
 
 
 def test_dryrun_results_all_ok():
