@@ -109,6 +109,15 @@ class count_dispatches:
         return False
 
 
+# Host spans on the profiler's clock: ``dima.call`` around a multibank
+# matvec/matmat (stats kind, mode, B queries, M stored rows) and, on the
+# single-launch paths, its children ``dima.prep`` (operands), ``dima.launch``
+# (the enqueue of the one execution) and ``dima.assemble`` (unpad, merge,
+# ragged bank).  Under ``jax.profiler.trace`` they land beside the device
+# ops; with tracing off each costs about a microsecond.
+_span = jax.profiler.TraceAnnotation
+
+
 def _check_mode(mode: str) -> None:
     if mode not in MODES:
         raise ValueError(f"unknown mode {mode!r}; expected one of {MODES}")
@@ -757,24 +766,30 @@ class MultiBankBackend(DimaBackend):
             raise ValueError(f"matvec wants stored (m, n); got "
                              f"{stored.shape}")
         _check_op_dims(stored.shape[-1], self.p)
-        if self.robust:
-            return self._robust_run("matvec", stored, jnp.asarray(query),
-                                    mode, key, v_range, trim)
-        if self.mesh is not None:
-            return self._matvec_mesh(stored, jnp.asarray(query), mode, key,
-                                     v_range, trim)
-        if self.fused and isinstance(self.inner, ReferenceBackend):
-            return self._fused_host("matvec", stored, jnp.asarray(query),
-                                    mode, key, v_range, trim)
-        if self.fused and isinstance(self.inner, PallasBackend):
-            return self._fused_pallas("matvec", stored, jnp.asarray(query),
-                                      mode, key, v_range, trim)
-        return self._merge(
-            [self.inner.matvec(stored[a:z], query, mode=mode,
-                               key=self._bank_key(key, b), v_range=v_range,
-                               trim=trim)
-             for b, (a, z) in enumerate(self.bank_slices(stored.shape[0]))],
-            axis=0)
+        with _span("dima.call", kind="matvec", mode=mode, B=1,
+                   M=stored.shape[0]):
+            if self.robust:
+                return self._robust_run("matvec", stored,
+                                        jnp.asarray(query), mode, key,
+                                        v_range, trim)
+            if self.mesh is not None:
+                return self._matvec_mesh(stored, jnp.asarray(query), mode,
+                                         key, v_range, trim)
+            if self.fused and isinstance(self.inner, ReferenceBackend):
+                return self._fused_host("matvec", stored,
+                                        jnp.asarray(query), mode, key,
+                                        v_range, trim)
+            if self.fused and isinstance(self.inner, PallasBackend):
+                return self._fused_pallas("matvec", stored,
+                                          jnp.asarray(query), mode, key,
+                                          v_range, trim)
+            return self._merge(
+                [self.inner.matvec(stored[a:z], query, mode=mode,
+                                   key=self._bank_key(key, b),
+                                   v_range=v_range, trim=trim)
+                 for b, (a, z) in enumerate(
+                     self.bank_slices(stored.shape[0]))],
+                axis=0)
 
     def matmat(self, stored, queries, *, mode="dp", key=None,
                v_range=None, trim=None) -> DimaOut:
@@ -784,24 +799,27 @@ class MultiBankBackend(DimaBackend):
             raise ValueError(f"matmat wants stored (m, n) × queries "
                              f"(b, n); got {stored.shape} × {queries.shape}")
         _check_op_dims(stored.shape[-1], self.p)
-        if self.robust:
-            return self._robust_run("matmat", stored, queries, mode, key,
-                                    v_range, trim)
-        if self.mesh is not None:
-            return self._matmat_mesh(stored, queries, mode, key, v_range,
-                                     trim)
-        if self.fused and isinstance(self.inner, ReferenceBackend):
-            return self._fused_host("matmat", stored, queries, mode, key,
-                                    v_range, trim)
-        if self.fused and isinstance(self.inner, PallasBackend):
-            return self._fused_pallas("matmat", stored, queries, mode, key,
-                                      v_range, trim)
-        return self._merge(
-            [self.inner.matmat(stored[a:z], queries, mode=mode,
-                               key=self._bank_key(key, b), v_range=v_range,
-                               trim=trim)
-             for b, (a, z) in enumerate(self.bank_slices(stored.shape[0]))],
-            axis=1)
+        with _span("dima.call", kind="matmat", mode=mode, B=queries.shape[0],
+                   M=stored.shape[0]):
+            if self.robust:
+                return self._robust_run("matmat", stored, queries, mode, key,
+                                        v_range, trim)
+            if self.mesh is not None:
+                return self._matmat_mesh(stored, queries, mode, key,
+                                         v_range, trim)
+            if self.fused and isinstance(self.inner, ReferenceBackend):
+                return self._fused_host("matmat", stored, queries, mode, key,
+                                        v_range, trim)
+            if self.fused and isinstance(self.inner, PallasBackend):
+                return self._fused_pallas("matmat", stored, queries, mode,
+                                          key, v_range, trim)
+            return self._merge(
+                [self.inner.matmat(stored[a:z], queries, mode=mode,
+                                   key=self._bank_key(key, b),
+                                   v_range=v_range, trim=trim)
+                 for b, (a, z) in enumerate(
+                     self.bank_slices(stored.shape[0]))],
+                axis=1)
 
     # -- robust path: per-physical-bank loop with variation/drift/faults ----
 
@@ -1034,14 +1052,19 @@ class MultiBankBackend(DimaBackend):
     def _fused_host(self, kind, stored, q, mode, key, v_range,
                     trim=None) -> DimaOut:
         m, n = stored.shape
-        rows_per, n_full, ragged = self._bank_split(m)
-        d_full = stored[:n_full * rows_per].reshape(n_full, rows_per, n)
-        d_rag = stored[n_full * rows_per:] if ragged else None
-        out = _dispatch(lambda: self._fused_fn(kind, mode)(
-            d_full, d_rag, q, self.chip, key, v_range, _trim_coef(trim)))
-        n_ops = m if kind == "matvec" else q.shape[0] * m
-        return DimaOut(out[0], out[1], n_ops * pl._cycles_per_op(n, self.p),
-                       n_ops, out[2] if len(out) == 3 else None)
+        with _span("dima.prep"):
+            rows_per, n_full, ragged = self._bank_split(m)
+            d_full = stored[:n_full * rows_per].reshape(n_full, rows_per, n)
+            d_rag = stored[n_full * rows_per:] if ragged else None
+            fn, coef = self._fused_fn(kind, mode), _trim_coef(trim)
+        with _span("dima.launch"):
+            out = _dispatch(lambda: fn(d_full, d_rag, q, self.chip, key,
+                                       v_range, coef))
+        with _span("dima.assemble"):
+            n_ops = m if kind == "matvec" else q.shape[0] * m
+            return DimaOut(out[0], out[1],
+                           n_ops * pl._cycles_per_op(n, self.p), n_ops,
+                           out[2] if len(out) == 3 else None)
 
     # -- fused pallas path: the banked kernel grid --------------------------
 
@@ -1050,45 +1073,49 @@ class MultiBankBackend(DimaBackend):
         from repro.kernels import ops as kops
         self.inner._require_kernel_mode(mode)
         m, n = stored.shape
-        rows_per, n_full, ragged = self._bank_split(m)
-        d = pl._pad_to_conversion(stored.astype(jnp.int32), self.p)
-        d_full = d[:n_full * rows_per].reshape(n_full, rows_per, d.shape[-1])
-        qp = pl._pad_to_conversion(q.astype(jnp.int32), self.p)
-        f = {("matvec", "dp"): kops.dima_dp_bank_matvec,
-             ("matvec", "md"): kops.dima_md_bank_matvec,
-             ("matmat", "dp"): kops.dima_dp_bank_matmat,
-             ("matmat", "md"): kops.dima_md_bank_matmat}[(kind, mode)]
-        out = _dispatch(lambda: f(
-            d_full.astype(jnp.uint8), qp.astype(jnp.uint8), self.p,
-            self.chip, key, v_range, interpret=self.inner.interpret,
-            trim=trim))
-        code, volts = out[0], out[1]
-        trimmed = out[2] if len(out) == 3 else None
-        if kind == "matvec":                # (nb, rows) -> (m_full,)
-            code, volts = code.reshape(-1), volts.reshape(-1)
-            if trimmed is not None:
-                trimmed = trimmed.reshape(-1)
-        else:                               # (nb, B, rows) -> (B, m_full)
-            if trimmed is not None:
-                trimmed = trimmed.transpose(1, 0, 2).reshape(q.shape[0], -1)
-            code, volts = _merge_banked(code, volts, q.shape[0])
-        if ragged:
-            # separate launch: the ragged bank's padded row count — and
-            # with it the noise-array shapes — differs from the full
-            # banks', and the counter-based PRNG is not prefix-stable
-            op = (self.inner.matvec if kind == "matvec"
-                  else self.inner.matmat)
-            out_r = op(stored[n_full * rows_per:], q, mode=mode,
-                       key=self._bank_key(key, n_full), v_range=v_range,
-                       trim=trim)
-            axis = 0 if kind == "matvec" else 1
-            code = jnp.concatenate([code, out_r.code], axis)
-            volts = jnp.concatenate([volts, out_r.volts], axis)
-            if trimmed is not None:
-                trimmed = jnp.concatenate([trimmed, out_r.trimmed], axis)
-        n_ops = m if kind == "matvec" else q.shape[0] * m
-        return DimaOut(code, volts, n_ops * pl._cycles_per_op(n, self.p),
-                       n_ops, trimmed)
+        matvec = kind == "matvec"
+        with _span("dima.prep"):
+            rows_per, n_full, ragged = self._bank_split(m)
+            d = pl._pad_to_conversion(stored.astype(jnp.int32), self.p)
+            d_full = d[:n_full * rows_per].reshape(n_full, rows_per,
+                                                   d.shape[-1])
+            qp = pl._pad_to_conversion(q.astype(jnp.int32), self.p)
+            operands = kops._bank_operands(
+                d_full.astype(jnp.uint8), qp.astype(jnp.uint8), self.p,
+                self.chip, v_range, mode, matvec, trim)
+        with _span("dima.launch"):
+            out = _dispatch(lambda: kops._bank_launch(
+                operands, key, self.p, mode, matvec, self.inner.interpret))
+        with _span("dima.assemble"):
+            out = kops._bank_unpad(out, rows_per, matvec)
+            code, volts = out[0], out[1]
+            trimmed = out[2] if len(out) == 3 else None
+            if matvec:                      # (nb, rows) -> (m_full,)
+                code, volts = code.reshape(-1), volts.reshape(-1)
+                if trimmed is not None:
+                    trimmed = trimmed.reshape(-1)
+            else:                           # (nb, B, rows) -> (B, m_full)
+                if trimmed is not None:
+                    trimmed = trimmed.transpose(1, 0, 2).reshape(
+                        q.shape[0], -1)
+                code, volts = _merge_banked(code, volts, q.shape[0])
+            if ragged:
+                # separate launch: the ragged bank's padded row count —
+                # and with it the noise-array shapes — differs from the
+                # full banks', and the counter-based PRNG is not
+                # prefix-stable
+                op = self.inner.matvec if matvec else self.inner.matmat
+                out_r = op(stored[n_full * rows_per:], q, mode=mode,
+                           key=self._bank_key(key, n_full), v_range=v_range,
+                           trim=trim)
+                axis = 0 if matvec else 1
+                code = jnp.concatenate([code, out_r.code], axis)
+                volts = jnp.concatenate([volts, out_r.volts], axis)
+                if trimmed is not None:
+                    trimmed = jnp.concatenate([trimmed, out_r.trimmed], axis)
+            n_ops = m if matvec else q.shape[0] * m
+            return DimaOut(code, volts, n_ops * pl._cycles_per_op(n, self.p),
+                           n_ops, trimmed)
 
     # -- device-mesh fan-out ------------------------------------------------
 
@@ -1187,39 +1214,44 @@ class MultiBankBackend(DimaBackend):
                 check_vma=False))
         return self._jit[k]
 
-    def _mesh_call(self, kind, banked, q, mode, key, v_range, trim):
-        f = self._mesh_fn(kind, mode, key is not None, v_range is not None,
-                          trim is not None)
-        key_op = (jnp.zeros((2,), jnp.uint32) if key is None
-                  else key)
-        vr_op = (jnp.zeros((2,), jnp.float32) if v_range is None
-                 else jnp.asarray(v_range, jnp.float32))
-        ep_op = (jnp.zeros((3,), jnp.float32) if trim is None
-                 else _trim_coef(trim))
-        return _dispatch(lambda: f(banked, q, key_op, vr_op, ep_op))
+    def _mesh_call(self, kind, stored, q, mode, key, v_range, trim):
+        """Stack the banks and run the shard_map'd function once: the
+        ``dima.prep`` and ``dima.launch`` spans of a mesh call."""
+        with _span("dima.prep"):
+            banked = self._mesh_banked(stored)
+            f = self._mesh_fn(kind, mode, key is not None,
+                              v_range is not None, trim is not None)
+            key_op = (jnp.zeros((2,), jnp.uint32) if key is None
+                      else key)
+            vr_op = (jnp.zeros((2,), jnp.float32) if v_range is None
+                     else jnp.asarray(v_range, jnp.float32))
+            ep_op = (jnp.zeros((3,), jnp.float32) if trim is None
+                     else _trim_coef(trim))
+        with _span("dima.launch"):
+            return _dispatch(lambda: f(banked, q, key_op, vr_op, ep_op))
 
     def _matvec_mesh(self, stored, query, mode, key, v_range,
                      trim=None) -> DimaOut:
         m, n = stored.shape
-        banked = self._mesh_banked(stored)
-        out = self._mesh_call("matvec", banked, query, mode, key, v_range,
+        out = self._mesh_call("matvec", stored, query, mode, key, v_range,
                               trim)
-        trimmed = out[2].reshape(m) if len(out) == 3 else None
-        return DimaOut(out[0].reshape(m), out[1].reshape(m),
-                       m * pl._cycles_per_op(n, self.p), m, trimmed)
+        with _span("dima.assemble"):
+            trimmed = out[2].reshape(m) if len(out) == 3 else None
+            return DimaOut(out[0].reshape(m), out[1].reshape(m),
+                           m * pl._cycles_per_op(n, self.p), m, trimmed)
 
     def _matmat_mesh(self, stored, queries, mode, key, v_range,
                      trim=None) -> DimaOut:
         m, n = stored.shape
         b = queries.shape[0]
-        banked = self._mesh_banked(stored)
-        out = self._mesh_call("matmat", banked, queries, mode, key, v_range,
+        out = self._mesh_call("matmat", stored, queries, mode, key, v_range,
                               trim)
-        trimmed = (out[2].transpose(1, 0, 2).reshape(b, -1)
-                   if len(out) == 3 else None)
-        code, volts = _merge_banked(out[0], out[1], b)
-        return DimaOut(code, volts, b * m * pl._cycles_per_op(n, self.p),
-                       b * m, trimmed)
+        with _span("dima.assemble"):
+            trimmed = (out[2].transpose(1, 0, 2).reshape(b, -1)
+                       if len(out) == 3 else None)
+            code, volts = _merge_banked(out[0], out[1], b)
+            return DimaOut(code, volts, b * m * pl._cycles_per_op(n, self.p),
+                           b * m, trimmed)
 
     # -- cost ---------------------------------------------------------------
 

@@ -219,15 +219,17 @@ def _stack_bank_noise(key, p: DimaParams, NB, Mp, kind, B=None, offset=0):
 def _bank_call_dp(d, qs, cg, ce, mg, mo, key, vr, ep, offset, *,
                   params: DimaParams, interpret, matvec):
     NB, Mp = d.shape[0], d.shape[1]
-    if matvec:
-        rn, cn = _stack_bank_noise(key, params, NB, Mp, "dp",
-                                   offset=offset)
-        rn, cn = rn[:, None], cn[:, None]
-    else:
-        rn, cn = _stack_bank_noise(key, params, NB, Mp, "dp",
-                                   B=qs.shape[0], offset=offset)
-    return _dima_dp_bank_kernel(d, qs, cg, ce, mg, mo, rn, cn, vr, ep,
-                                params=params, interpret=interpret)
+    with jax.named_scope("dima.noise"):
+        if matvec:
+            rn, cn = _stack_bank_noise(key, params, NB, Mp, "dp",
+                                       offset=offset)
+            rn, cn = rn[:, None], cn[:, None]
+        else:
+            rn, cn = _stack_bank_noise(key, params, NB, Mp, "dp",
+                                       B=qs.shape[0], offset=offset)
+    with jax.named_scope("dima.kernel"):
+        return _dima_dp_bank_kernel(d, qs, cg, ce, mg, mo, rn, cn, vr, ep,
+                                    params=params, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -235,16 +237,59 @@ def _bank_call_dp(d, qs, cg, ce, mg, mo, key, vr, ep, offset, *,
 def _bank_call_md(d, qs, cg, ce, key, vr, ep, offset, *,
                   params: DimaParams, interpret, matvec):
     NB, Mp = d.shape[0], d.shape[1]
+    with jax.named_scope("dima.noise"):
+        if matvec:
+            cmp_n, rn, rnb, cn = _stack_bank_noise(key, params, NB, Mp, "md",
+                                                   offset=offset)
+            cmp_n, rn, rnb, cn = (cmp_n[:, None], rn[:, None], rnb[:, None],
+                                  cn[:, None])
+        else:
+            cmp_n, rn, rnb, cn = _stack_bank_noise(key, params, NB, Mp, "md",
+                                                   B=qs.shape[0],
+                                                   offset=offset)
+    with jax.named_scope("dima.kernel"):
+        return _dima_md_bank_kernel(d, qs, cg, ce, cmp_n, rn, rnb, cn, vr,
+                                    ep, params=params, interpret=interpret)
+
+
+def _bank_operands(d, q_or_qs, p, chip, v_range, mode, matvec, trim=None,
+                   bank_offset=0):
+    """The fused launch's operands, ``(d, qs, consts, vr, ep, offset)``:
+    each bank's rows padded to the 128-row block, the chip arrays the
+    mode's kernel reads, the per-bank ADC windows, the fused-trim
+    operand and the first bank's global index."""
+    NB = d.shape[0]
+    dp_ = _pad_to(jnp.asarray(d, jnp.uint8), 128, 1)
+    cg, ce, mg, mo = _chip_arrays(chip, p)
+    if v_range is None:
+        from repro.core.pipeline import dp_gain, md_gain
+        v_range = ((0.0, 255.0 * 255.0 * dp_gain(p)) if mode == "dp"
+                   else (0.0, 255.0 * md_gain(p)))
+    vr = jnp.asarray(v_range, jnp.float32)
+    if vr.ndim == 1:
+        vr = vr[None]
+    if vr.shape[0] != NB:                  # shared window -> one row/bank
+        vr = jnp.broadcast_to(vr, (NB, 2))
+    qs = jnp.asarray(q_or_qs, jnp.uint8)
+    qs2 = qs.reshape(1, -1) if matvec else qs
+    consts = (cg, ce, mg, mo) if mode == "dp" else (cg, ce)
+    return (dp_, qs2, consts, vr, _trim_ep(trim, qs2),
+            jnp.asarray(bank_offset, jnp.int32))
+
+
+def _bank_launch(operands, key, p, mode, matvec, interpret):
+    """The one jitted execution: noise stacks and the kernel grid."""
+    d, qs, consts, vr, ep, offset = operands
+    call = _bank_call_dp if mode == "dp" else _bank_call_md
+    return call(d, qs, *consts, key, vr, ep, offset, params=p,
+                interpret=interpret, matvec=matvec)
+
+
+def _bank_unpad(out, M, matvec):
+    """Drop the padding rows: (NB, M) for a matvec, (NB, B, M) else."""
     if matvec:
-        cmp_n, rn, rnb, cn = _stack_bank_noise(key, params, NB, Mp, "md",
-                                               offset=offset)
-        cmp_n, rn, rnb, cn = (cmp_n[:, None], rn[:, None], rnb[:, None],
-                              cn[:, None])
-    else:
-        cmp_n, rn, rnb, cn = _stack_bank_noise(key, params, NB, Mp, "md",
-                                               B=qs.shape[0], offset=offset)
-    return _dima_md_bank_kernel(d, qs, cg, ce, cmp_n, rn, rnb, cn, vr, ep,
-                                params=params, interpret=interpret)
+        return tuple(o[:, 0, :M] for o in out)
+    return tuple(o[:, :, :M] for o in out)
 
 
 def _bank_fused(d, q_or_qs, p, chip, key, v_range, interpret, mode, matvec,
@@ -259,31 +304,10 @@ def _bank_fused(d, q_or_qs, p, chip, key, v_range, interpret, mode, matvec,
     coefficient triple switching on the in-kernel calibration epilogue;
     ``bank_offset`` the global index of bank 0 for the fold_in streams
     (the mesh path's shard start, possibly traced)."""
-    NB, M = d.shape[0], d.shape[1]
-    dp_ = _pad_to(jnp.asarray(d, jnp.uint8), 128, 1)
-    cg, ce, mg, mo = _chip_arrays(chip, p)
-    if v_range is None:
-        from repro.core.pipeline import dp_gain, md_gain
-        v_range = ((0.0, 255.0 * 255.0 * dp_gain(p)) if mode == "dp"
-                   else (0.0, 255.0 * md_gain(p)))
-    vr = jnp.asarray(v_range, jnp.float32)
-    if vr.ndim == 1:
-        vr = vr[None]
-    if vr.shape[0] != NB:                  # shared window -> one row/bank
-        vr = jnp.broadcast_to(vr, (NB, 2))
-    qs = jnp.asarray(q_or_qs, jnp.uint8)
-    qs2 = qs.reshape(1, -1) if matvec else qs
-    ep = _trim_ep(trim, qs2)
-    offset = jnp.asarray(bank_offset, jnp.int32)
-    if mode == "dp":
-        out = _bank_call_dp(dp_, qs2, cg, ce, mg, mo, key, vr, ep, offset,
-                            params=p, interpret=interpret, matvec=matvec)
-    else:
-        out = _bank_call_md(dp_, qs2, cg, ce, key, vr, ep, offset,
-                            params=p, interpret=interpret, matvec=matvec)
-    if matvec:
-        return tuple(o[:, 0, :M] for o in out)       # (NB, M)
-    return tuple(o[:, :, :M] for o in out)           # (NB, B, M)
+    operands = _bank_operands(d, q_or_qs, p, chip, v_range, mode, matvec,
+                              trim, bank_offset)
+    return _bank_unpad(_bank_launch(operands, key, p, mode, matvec,
+                                    interpret), d.shape[1], matvec)
 
 
 def dima_dp_bank_matvec(d, q, p: DimaParams = DimaParams(), chip=None,
