@@ -111,10 +111,11 @@ class count_dispatches:
 
 # Host spans on the profiler's clock: ``dima.call`` around a multibank
 # matvec/matmat (stats kind, mode, B queries, M stored rows) and, on the
-# single-launch paths, its children ``dima.prep`` (operands), ``dima.launch``
-# (the enqueue of the one execution) and ``dima.assemble`` (unpad, merge,
-# ragged bank).  Under ``jax.profiler.trace`` they land beside the device
-# ops; with tracing off each costs about a microsecond.
+# single-launch paths, its children ``dima.prep`` (host bookkeeping before
+# the launch), ``dima.launch`` (the enqueue of the one execution) and
+# ``dima.assemble`` (the output, the ragged bank).  Under
+# ``jax.profiler.trace`` they land beside the device ops; with tracing off
+# each costs about a microsecond.
 _span = jax.profiler.TraceAnnotation
 
 
@@ -547,15 +548,6 @@ def _bank_matmat(d_b, qs, p, chip, bank_key, mode, v_range):
     """One bank's matmat (per-query keys = ``split(bank_key, b)``, the
     convention ``pl.dima_matmat`` defines once)."""
     return pl.dima_matmat(d_b, qs, p, chip, bank_key, mode, v_range)
-
-
-def _merge_banked(code, volts, b):
-    """The matmat digital merge: per-bank (n_banks, B, rows) blocks ->
-    (B, m) in bank-contiguous row order.  Defined ONCE for the host
-    fused, pallas and mesh paths — the bitwise host==pallas==mesh parity
-    depends on all three merging in the same order."""
-    return (code.transpose(1, 0, 2).reshape(b, -1),
-            volts.transpose(1, 0, 2).reshape(b, -1))
 
 
 @register_backend("multibank")
@@ -1028,7 +1020,7 @@ class MultiBankBackend(DimaBackend):
                 if kind == "matvec":
                     code, volts = code.reshape(-1), volts.reshape(-1)
                 else:
-                    code, volts = _merge_banked(code, volts, q.shape[0])
+                    code, volts = pl._merge_banked(code, volts)
                 if d_rag is not None:
                     rk = (None if key is None
                           else jax.random.fold_in(key, nb))
@@ -1076,29 +1068,14 @@ class MultiBankBackend(DimaBackend):
         matvec = kind == "matvec"
         with _span("dima.prep"):
             rows_per, n_full, ragged = self._bank_split(m)
-            d = pl._pad_to_conversion(stored.astype(jnp.int32), self.p)
-            d_full = d[:n_full * rows_per].reshape(n_full, rows_per,
-                                                   d.shape[-1])
-            qp = pl._pad_to_conversion(q.astype(jnp.int32), self.p)
-            operands = kops._bank_operands(
-                d_full.astype(jnp.uint8), qp.astype(jnp.uint8), self.p,
-                self.chip, v_range, mode, matvec, trim)
         with _span("dima.launch"):
-            out = _dispatch(lambda: kops._bank_launch(
-                operands, key, self.p, mode, matvec, self.inner.interpret))
+            out = _dispatch(lambda: kops._bank_call(
+                stored, q, self.chip, key, v_range, trim, 0, params=self.p,
+                interpret=self.inner.interpret, mode=mode, matvec=matvec,
+                rows_per=rows_per))
         with _span("dima.assemble"):
-            out = kops._bank_unpad(out, rows_per, matvec)
             code, volts = out[0], out[1]
             trimmed = out[2] if len(out) == 3 else None
-            if matvec:                      # (nb, rows) -> (m_full,)
-                code, volts = code.reshape(-1), volts.reshape(-1)
-                if trimmed is not None:
-                    trimmed = trimmed.reshape(-1)
-            else:                           # (nb, B, rows) -> (B, m_full)
-                if trimmed is not None:
-                    trimmed = trimmed.transpose(1, 0, 2).reshape(
-                        q.shape[0], -1)
-                code, volts = _merge_banked(code, volts, q.shape[0])
             if ragged:
                 # separate launch: the ragged bank's padded row count —
                 # and with it the noise-array shapes — differs from the
@@ -1172,11 +1149,7 @@ class MultiBankBackend(DimaBackend):
 
                 def per_shard(d_blk, q, key, vr, ep):
                     start = jax.lax.axis_index("banks") * d_blk.shape[0]
-                    dp = pl._pad_to_conversion(
-                        d_blk.astype(jnp.int32), p).astype(jnp.uint8)
-                    qp = pl._pad_to_conversion(
-                        q.astype(jnp.int32), p).astype(jnp.uint8)
-                    return kf(dp, qp, p, chip,
+                    return kf(d_blk, q, p, chip,
                               key if has_key else None,
                               (vr[0], vr[1]) if has_vr else None,
                               interpret=interp,
@@ -1247,9 +1220,8 @@ class MultiBankBackend(DimaBackend):
         out = self._mesh_call("matmat", stored, queries, mode, key, v_range,
                               trim)
         with _span("dima.assemble"):
-            trimmed = (out[2].transpose(1, 0, 2).reshape(b, -1)
-                       if len(out) == 3 else None)
-            code, volts = _merge_banked(out[0], out[1], b)
+            code, volts, *trimmed = pl._merge_banked(*out)
+            trimmed = trimmed[0] if trimmed else None
             return DimaOut(code, volts, b * m * pl._cycles_per_op(n, self.p),
                            b * m, trimmed)
 

@@ -64,6 +64,15 @@ def _cycle_split(x, n_cycles, w):
     return x.reshape(x.shape[:-1] + (n_cycles, w))
 
 
+def _merge_banked(*blocks):
+    """The matmat digital merge: per-bank (n_banks, B, rows) blocks ->
+    (B, m) in bank-contiguous row order.  Defined ONCE for the host
+    fused, pallas and mesh paths — the bitwise host==pallas==mesh parity
+    depends on all three merging in the same order."""
+    return tuple(x.transpose(1, 0, 2).reshape(x.shape[1], -1)
+                 for x in blocks)
+
+
 def _fold_each(key, idx):
     """fold_in over an index vector -> stacked keys (vmap-invariant, so
     each row equals the seed loop's ``fold_in(key, i)``)."""

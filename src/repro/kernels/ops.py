@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import pipeline as pl
 from repro.core.params import DimaParams
 from repro.kernels import ref as ref_mod
 from repro.kernels.dima_dp import dima_dp as _dima_dp_kernel
@@ -210,104 +211,96 @@ def _stack_bank_noise(key, p: DimaParams, NB, Mp, kind, B=None, offset=0):
            else (lambda k: _batch_noise(k, p, B, Mp, kind)))
     if key is None:
         return tuple(jnp.zeros((NB,) + a.shape, a.dtype) for a in one(None))
-    from repro.core.pipeline import _fold_each
-    return jax.vmap(one)(_fold_each(key, offset + jnp.arange(NB)))
+    return jax.vmap(one)(pl._fold_each(key, offset + jnp.arange(NB)))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("params", "interpret", "matvec"))
-def _bank_call_dp(d, qs, cg, ce, mg, mo, key, vr, ep, offset, *,
-                  params: DimaParams, interpret, matvec):
-    NB, Mp = d.shape[0], d.shape[1]
-    with jax.named_scope("dima.noise"):
-        if matvec:
-            rn, cn = _stack_bank_noise(key, params, NB, Mp, "dp",
-                                       offset=offset)
-            rn, cn = rn[:, None], cn[:, None]
-        else:
-            rn, cn = _stack_bank_noise(key, params, NB, Mp, "dp",
-                                       B=qs.shape[0], offset=offset)
-    with jax.named_scope("dima.kernel"):
-        return _dima_dp_bank_kernel(d, qs, cg, ce, mg, mo, rn, cn, vr, ep,
-                                    params=params, interpret=interpret)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("params", "interpret", "matvec"))
-def _bank_call_md(d, qs, cg, ce, key, vr, ep, offset, *,
-                  params: DimaParams, interpret, matvec):
-    NB, Mp = d.shape[0], d.shape[1]
-    with jax.named_scope("dima.noise"):
-        if matvec:
-            cmp_n, rn, rnb, cn = _stack_bank_noise(key, params, NB, Mp, "md",
-                                                   offset=offset)
-            cmp_n, rn, rnb, cn = (cmp_n[:, None], rn[:, None], rnb[:, None],
-                                  cn[:, None])
-        else:
-            cmp_n, rn, rnb, cn = _stack_bank_noise(key, params, NB, Mp, "md",
-                                                   B=qs.shape[0],
-                                                   offset=offset)
-    with jax.named_scope("dima.kernel"):
-        return _dima_md_bank_kernel(d, qs, cg, ce, cmp_n, rn, rnb, cn, vr,
-                                    ep, params=params, interpret=interpret)
-
-
-def _bank_operands(d, q_or_qs, p, chip, v_range, mode, matvec, trim=None,
-                   bank_offset=0):
-    """The fused launch's operands, ``(d, qs, consts, vr, ep, offset)``:
-    each bank's rows padded to the 128-row block, the chip arrays the
-    mode's kernel reads, the per-bank ADC windows, the fused-trim
-    operand and the first bank's global index."""
+def _bank_operands(d, q_or_qs, p, chip, v_range, mode, matvec, trim,
+                   offset, rows_per):
+    """Traced: the kernel operands from the caller's raw ones, ``(d, qs,
+    consts, vr, ep, offset)``.  ``d`` is either the stored rows (m, n),
+    split here into ``m // rows_per`` full banks, or banks already
+    stacked (NB, M, n).  Rows and queries are padded to one conversion
+    and cast to u8, each bank's rows padded to the 128-row block; then
+    the chip arrays the mode's kernel reads, the per-bank ADC windows
+    (a shared (lo, hi) or a per-bank (NB, 2)), the fused-trim operand
+    and the first bank's global index."""
+    d = pl._pad_to_conversion(d.astype(jnp.int32), p).astype(jnp.uint8)
+    if d.ndim == 2:
+        n_full = d.shape[0] // rows_per
+        d = d[:n_full * rows_per].reshape(n_full, rows_per, d.shape[-1])
     NB = d.shape[0]
-    dp_ = _pad_to(jnp.asarray(d, jnp.uint8), 128, 1)
+    dp_ = _pad_to(d, 128, 1)
     cg, ce, mg, mo = _chip_arrays(chip, p)
     if v_range is None:
-        from repro.core.pipeline import dp_gain, md_gain
-        v_range = ((0.0, 255.0 * 255.0 * dp_gain(p)) if mode == "dp"
-                   else (0.0, 255.0 * md_gain(p)))
+        v_range = ((0.0, 255.0 * 255.0 * pl.dp_gain(p)) if mode == "dp"
+                   else (0.0, 255.0 * pl.md_gain(p)))
     vr = jnp.asarray(v_range, jnp.float32)
     if vr.ndim == 1:
         vr = vr[None]
     if vr.shape[0] != NB:                  # shared window -> one row/bank
         vr = jnp.broadcast_to(vr, (NB, 2))
-    qs = jnp.asarray(q_or_qs, jnp.uint8)
+    qs = pl._pad_to_conversion(q_or_qs.astype(jnp.int32),
+                               p).astype(jnp.uint8)
     qs2 = qs.reshape(1, -1) if matvec else qs
     consts = (cg, ce, mg, mo) if mode == "dp" else (cg, ce)
     return (dp_, qs2, consts, vr, _trim_ep(trim, qs2),
-            jnp.asarray(bank_offset, jnp.int32))
-
-
-def _bank_launch(operands, key, p, mode, matvec, interpret):
-    """The one jitted execution: noise stacks and the kernel grid."""
-    d, qs, consts, vr, ep, offset = operands
-    call = _bank_call_dp if mode == "dp" else _bank_call_md
-    return call(d, qs, *consts, key, vr, ep, offset, params=p,
-                interpret=interpret, matvec=matvec)
+            jnp.asarray(offset, jnp.int32))
 
 
 def _bank_unpad(out, M, matvec):
-    """Drop the padding rows: (NB, M) for a matvec, (NB, B, M) else."""
+    """Traced: drop the padding rows, (NB, M) for a matvec, (NB, B, M)
+    else."""
     if matvec:
         return tuple(o[:, 0, :M] for o in out)
     return tuple(o[:, :, :M] for o in out)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "params", "interpret", "mode", "matvec", "rows_per"))
+def _bank_call(d, q_or_qs, chip, key, v_range, trim, offset, *,
+               params: DimaParams, interpret, mode, matvec, rows_per=None):
+    """The banked step as ONE execution: operands, noise stacks, the
+    (NB, B, M/128) kernel grid and the unpad.  Stored rows (m, n) come
+    back merged in row order, (m_full,) or (B, m_full); stacked banks
+    (NB, M, n) come back per bank, (NB, M) or (NB, B, M)."""
+    rows = d.ndim == 2
+    M = rows_per if rows else d.shape[1]
+    d, qs, consts, vr, ep, offset = _bank_operands(
+        d, q_or_qs, params, chip, v_range, mode, matvec, trim, offset,
+        rows_per)
+    NB, Mp = d.shape[0], d.shape[1]
+    with jax.named_scope("dima.noise"):
+        noise = _stack_bank_noise(key, params, NB, Mp, mode,
+                                  B=None if matvec else qs.shape[0],
+                                  offset=offset)
+        if matvec:
+            noise = tuple(a[:, None] for a in noise)
+    kernel = _dima_dp_bank_kernel if mode == "dp" else _dima_md_bank_kernel
+    with jax.named_scope("dima.kernel"):
+        out = kernel(d, qs, *consts, *noise, vr, ep, params=params,
+                     interpret=interpret)
+    out = _bank_unpad(out, M, matvec)
+    if not rows:
+        return out
+    if matvec:                              # (NB, rows) -> (m_full,)
+        return tuple(o.reshape(-1) for o in out)
+    return pl._merge_banked(*out)           # (NB, B, rows) -> (B, m_full)
+
+
 def _bank_fused(d, q_or_qs, p, chip, key, v_range, interpret, mode, matvec,
                 trim=None, bank_offset=0):
-    """Shared driver: pad each bank's rows to the 128-row block, build
-    the per-bank noise stacks, launch the (NB, B, M/128) kernel once,
-    trim the padding.  Noise generation + launch run inside one jit, so
-    a fused banked op is a single dispatched computation.
+    """The public banked and plane wrappers' common entry: the
+    stacked banks through ``_bank_call``, so a fused banked op is a
+    single execution with per-bank outputs.
 
     ``v_range`` may be a shared (lo, hi) window or a per-bank (NB, 2)
     array (the bitserial per-plane calibrated windows); ``trim`` a
     coefficient triple switching on the in-kernel calibration epilogue;
     ``bank_offset`` the global index of bank 0 for the fold_in streams
     (the mesh path's shard start, possibly traced)."""
-    operands = _bank_operands(d, q_or_qs, p, chip, v_range, mode, matvec,
-                              trim, bank_offset)
-    return _bank_unpad(_bank_launch(operands, key, p, mode, matvec,
-                                    interpret), d.shape[1], matvec)
+    return _bank_call(d, q_or_qs, chip, key, v_range, trim, bank_offset,
+                      params=p, interpret=interpret, mode=mode,
+                      matvec=matvec)
 
 
 def dima_dp_bank_matvec(d, q, p: DimaParams = DimaParams(), chip=None,

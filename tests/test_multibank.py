@@ -156,25 +156,82 @@ def test_fused_matches_loop_bitwise(mode, m, n_banks):
                               key=key, counts=True)
 
 
+def _pallas_bank_loop(m, n_banks, kind, q, mode, key, v_range):
+    """Per-bank Pallas launches, bank ``b`` under its own window row
+    ``v_range[b]``: the oracle for a per-bank (n_banks, 2) window."""
+    inner = dima.get_backend("pallas", P, CHIP)
+    mb = dima.get_backend("multibank", P, CHIP, n_banks=n_banks)
+    outs = [getattr(inner, kind)(D[a:z], q, mode=mode,
+                                 key=jax.random.fold_in(key, b),
+                                 v_range=tuple(v_range[b]))
+            for b, (a, z) in enumerate(mb.bank_slices(m))]
+    return mb._merge(outs, axis=0 if kind == "matvec" else 1)
+
+
+# operand forms the fused step takes inside its jit: v_range None, a
+# shared (lo, hi) window or a per-bank (n_banks, 2) array; trim None or
+# a numpy triple with the benchmark's numpy uint32 key pair
+FUSED_PALLAS_FORMS = [
+    pytest.param(200, 4, "plain", id="200-4"),
+    pytest.param(50, 8, "plain", id="50-8"),
+    pytest.param(200, 4, "window", id="200-4-window"),
+    pytest.param(50, 8, "window", id="50-8-window"),
+    pytest.param(200, 4, "banks", id="200-4-banks"),
+    pytest.param(200, 4, "trim", id="200-4-trim"),
+    pytest.param(50, 8, "trim", id="50-8-trim"),
+]
+
+
 @pytest.mark.parametrize("mode", ["dp", "md"])
-@pytest.mark.parametrize("m,n_banks", [(200, 4), (50, 8)])
-def test_fused_pallas_inner_matches_loop(mode, m, n_banks):
+@pytest.mark.parametrize("m,n_banks,form", FUSED_PALLAS_FORMS)
+def test_fused_pallas_inner_matches_loop(mode, m, n_banks, form):
     """Pallas inner, interpret mode: the fused (n_banks, B, rows/128)
     bank-grid launch matches the per-bank kernel-launch loop — codes
     bitwise (full banks AND the separately-launched ragged remainder);
     volts to 1 ulp (XLA reassociation across the different launch
-    shapes, same envelope as the jitted-reference precedent)."""
+    shapes, same envelope as the jitted-reference precedent); trimmed
+    scores to the fused epilogue's float tolerance."""
     fused = dima.get_backend("multibank", P, CHIP, inner="pallas",
                              n_banks=n_banks)
     loop = dima.get_backend("multibank", P, CHIP, inner="pallas",
                             n_banks=n_banks, fused=False)
-    for key in (None, KEY):
-        assert_bitwise_parity("matvec", loop, fused, D[:m], Q, mode=mode,
-                              key=key, volts_atol=1e-7, counts=False)
-        am = fused.matmat(D[:m], QS, mode=mode, key=key)
-        assert am.code.shape == (3, m)
-        assert_bitwise_parity("matmat", loop, fused, D[:m], QS, mode=mode,
-                              key=key, volts_atol=1e-7, counts=False)
+    full = (255.0 * 255.0 * dima.dp_gain(P) if mode == "dp"
+            else 255.0 * dima.md_gain(P))
+    if form == "plain":
+        for key in (None, KEY):
+            assert_bitwise_parity("matvec", loop, fused, D[:m], Q,
+                                  mode=mode, key=key, volts_atol=1e-7,
+                                  counts=False)
+            am = fused.matmat(D[:m], QS, mode=mode, key=key)
+            assert am.code.shape == (3, m)
+            assert_bitwise_parity("matmat", loop, fused, D[:m], QS,
+                                  mode=mode, key=key, volts_atol=1e-7,
+                                  counts=False)
+        return
+    kw = dict(mode=mode, key=KEY)
+    if form == "window":
+        kw["v_range"] = (0.1 * full, 0.6 * full)
+    elif form == "banks":
+        kw["v_range"] = np.stack([np.linspace(0.0, 0.2, n_banks) * full,
+                                  np.linspace(0.5, 0.8, n_banks) * full],
+                                 1).astype(np.float32)
+    else:
+        kw.update(key=np.asarray(KEY, np.uint32),
+                  trim=np.asarray([0.97, -0.4, 12.5], np.float32))
+    for kind, q in (("matvec", Q), ("matmat", QS)):
+        got = getattr(fused, kind)(D[:m], q, **kw)
+        if form == "banks":
+            want = _pallas_bank_loop(m, n_banks, kind, q, mode, KEY,
+                                     kw["v_range"])
+        else:
+            want = getattr(loop, kind)(D[:m], q, **kw)
+        assert_outs_equal(want, got, volts_atol=1e-7, counts=False,
+                          label=f"{kind}/{form}")
+        if form == "trim":
+            assert got.trimmed.shape == got.code.shape
+            np.testing.assert_allclose(np.asarray(got.trimmed),
+                                       np.asarray(want.trimmed),
+                                       rtol=2e-6, atol=1e-2)
 
 
 def test_fused_dispatch_counts():

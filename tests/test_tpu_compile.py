@@ -69,6 +69,26 @@ def test_banked_kernel_compiles(one_chip, mode, op, trim):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("op", ["matvec", "matmat"])
+@pytest.mark.parametrize("mode", ["dp", "md"])
+def test_multibank_step_compiles(one_chip, mode, op):
+    """The multibank backend's one execution per call: stored rows
+    (2048, 256) split into 32 banks of 64, cast, padded, drawn for,
+    launched and merged inside ``_bank_call``, with a (lo, hi) window
+    and a trim triple as operands (no key: the noise stacks are the
+    banked kernels' own, and compile for tens of seconds)."""
+    rows = jax.ShapeDtypeStruct((2048, 256), jnp.uint8, sharding=one_chip)
+    q = jax.ShapeDtypeStruct((256,) if op == "matvec" else (B, 256),
+                             jnp.uint8, sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    trim = jax.ShapeDtypeStruct((3,), jnp.float32, sharding=one_chip)
+    lowered = ops._bank_call.lower(
+        rows, q, None, None, (f32, f32), trim, 0, params=P,
+        interpret=False, mode=mode, matvec=op == "matvec", rows_per=64)
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("op", ["banked", "matmat"])
 @pytest.mark.parametrize("mode", ["dp", "md"])
 def test_single_bank_kernel_compiles(one_chip, mode, op):

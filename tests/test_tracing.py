@@ -1,8 +1,8 @@
 """The program's own spans and scopes on the fused banked path.
 
-* The jitted banked bodies (``kernels/ops.py::_bank_call_dp/md``) scope
-  their noise stacks ``dima.noise`` and their kernel launch
-  ``dima.kernel``; XLA keeps both in the ops' ``op_name``.
+* The jitted banked step (``kernels/ops.py::_bank_call``) scopes its
+  noise stacks ``dima.noise`` and its kernel launch ``dima.kernel``;
+  XLA keeps both in the ops' ``op_name``.
 * A ``MultiBankBackend`` matvec/matmat is one ``dima.call`` host span
   (stats ``kind``, ``mode``, ``B``, ``M``) holding ``dima.prep``,
   ``dima.launch`` and ``dima.assemble`` in that order.
@@ -20,6 +20,7 @@ from jax.profiler import ProfileData
 from repro import dima
 from repro.core import api as api_mod
 from repro.core import noise as noise_mod
+from repro.core import pipeline as pl
 from repro.core.params import DimaParams
 from repro.kernels import ops
 
@@ -37,14 +38,10 @@ CHILDREN = ["dima.prep", "dima.launch", "dima.assemble"]
 @pytest.mark.parametrize("matvec", [True, False], ids=["matvec", "matmat"])
 @pytest.mark.parametrize("mode", ["dp", "md"])
 def test_banked_body_scopes_noise_and_kernel(mode, matvec):
-    d = STORED.reshape(NB, -1, 256)
-    operands = ops._bank_operands(d, QS[0] if matvec else QS, P, CHIP,
-                                  None, mode, matvec, TRIM)
-    dp_, qs, consts, vr, ep, offset = operands
-    call = ops._bank_call_dp if mode == "dp" else ops._bank_call_md
-    text = call.lower(dp_, qs, *consts, KEY, vr, ep, offset, params=P,
-                      interpret=True, matvec=matvec).as_text(
-                          debug_info=True)
+    text = ops._bank_call.lower(
+        STORED, QS[0] if matvec else QS, CHIP, KEY, None, TRIM, 0,
+        params=P, interpret=True, mode=mode, matvec=matvec,
+        rows_per=STORED.shape[0] // NB).as_text(debug_info=True)
     locs = [ln for ln in text.splitlines() if ln.startswith("#loc")]
     noise = [ln for ln in locs if "/dima.noise/" in ln]
     kernel = [ln for ln in locs if "/dima.kernel/" in ln]
@@ -60,7 +57,7 @@ def _backend():
                             n_banks=NB)
 
 
-def _host_events(trace_dir):
+def _host_events(trace_dir, prefix="dima."):
     path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     prof = ProfileData.from_file(path)
@@ -68,7 +65,7 @@ def _host_events(trace_dir):
                    for plane in prof.planes
                    if plane.name.startswith("/host:")
                    for line in plane.lines for e in line.events
-                   if e.name.startswith("dima.")), key=lambda e: e[1])
+                   if e.name.startswith(prefix)), key=lambda e: e[1])
 
 
 @pytest.mark.parametrize("kind", ["matvec", "matmat"])
@@ -88,6 +85,30 @@ def test_one_call_span_holds_its_three_children(tmp_path, kind):
                      "M": STORED.shape[0]}
     ends = [a] + [x for _, s, e, _ in children for x in (s, e)] + [b]
     assert ends == sorted(ends)          # nested, in order, not overlapping
+
+
+@pytest.mark.parametrize("mode", ["dp", "md"])
+@pytest.mark.parametrize("kind", ["matvec", "matmat"])
+def test_warm_call_is_one_execution(tmp_path, kind, mode):
+    """From the start of ``dima.prep`` to the end of ``dima.assemble`` a
+    warm fused-Pallas call runs exactly one compiled program: casts,
+    pads, unpad and merge run inside the banked step, and the benchmark's
+    host constants (a (lo, hi) window, numpy trim and key) go straight
+    into it."""
+    be = _backend()
+    q = QS[0] if kind == "matvec" else QS
+    op = getattr(be, kind)
+    kw = dict(mode=mode, key=np.asarray(KEY, np.uint32), trim=TRIM,
+              v_range=(0.0, 1.0))
+    jax.block_until_ready(op(STORED, q, **kw).code)
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(op(STORED, q, **kw).code)
+    events = _host_events(str(tmp_path), prefix="")
+    spans = {n: (a, b) for n, a, b, _ in events if n in CHILDREN}
+    start, end = spans["dima.prep"][0], spans["dima.assemble"][1]
+    runs = [n for n, a, _, _ in events
+            if n.endswith("Executable::Execute") and start <= a <= end]
+    assert len(runs) == 1, runs
 
 
 @pytest.mark.parametrize("m", [NB * 16, NB * 16 - 5], ids=["full", "ragged"])
@@ -113,4 +134,4 @@ def test_tracing_changes_no_code_and_no_dispatch(tmp_path, kind, m):
         np.testing.assert_array_equal(
             np.asarray(out.code),
             np.asarray(code.reshape(-1) if kind == "matvec"
-                       else api_mod._merge_banked(code, code, 2)[0]))
+                       else pl._merge_banked(code)[0]))
