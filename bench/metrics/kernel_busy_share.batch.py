@@ -1,6 +1,6 @@
 """Share of the device's busy time spent in the operations the program
 scopes ``dima.kernel`` (kernels/ops.py: the banked Pallas launch inside
-the jitted ``_bank_call_dp/md``), in %."""
+the jitted ``_bank_call``), in %."""
 from bench import program_trace as pt
 
 

@@ -104,9 +104,11 @@ def kernel_s(s: Summary, patterns) -> float:
                for ops in s.ops) / s.n_devices * 1e-9
 
 
-def kernel_count(s: Summary, patterns) -> int:
-    """Launches of the matching operations on the busiest device."""
-    return max((len(matching(ops, patterns)) for ops in s.ops), default=0)
+def calls(s: Summary) -> int:
+    """Calls in the traced window: its ``bench.call`` host spans.  The
+    window opens the trace before its first call and closes it after its
+    last call's fetch, so the trace's edge cuts no call."""
+    return sum(1 for name, *_ in s.host_spans if name == "bench.call")
 
 
 def executions(s: Summary) -> int:

@@ -1,33 +1,53 @@
-"""``correct`` on a whole run at a small size on the CPU: the program
-passes, and the plain reference in bfloat16 put in its place fails."""
-import os
+"""``correct`` on a whole run at a small size on the CPU, for every cell
+of ``BENCHMARK.json``: the program passes, and the plain reference in
+bfloat16 put in its place fails."""
+import json
 import sys
 import time
+from pathlib import Path
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
 
 from bench import harness  # noqa: E402
 
-#: two banks of 128 rows; few queries, so interpret mode keeps up
-SIZES = {
-    "batch256": {"n_banks": 2, "queries_per_call": 4, "pool_calls": 2,
-                 "cal_queries": 8, "compared_calls": 4},
-    "single": {"n_banks": 2, "pool_calls": 8, "cal_queries": 8,
-               "compared_calls": 8},
-}
-CELLS = ["knn-md-32bank.batch256", "mf-dp-32bank.batch256",
-         "mf-dp-32bank.single"]
+#: a cell's small sizes, by its traffic's queries per call: two banks of
+#: the configuration's rows, and few queries, so interpret mode keeps up
+N_BANKS = 2
+BATCH = {"queries_per_call": 4, "pool_calls": 2, "cal_queries": 8,
+         "compared_calls": 4}
+SINGLE = {"pool_calls": 8, "cal_queries": 8, "compared_calls": 8}
 SEED = 2 ** 31 + 977
 
 
-def small_run(name, system_cls=harness.Program, seconds=1.0):
-    cell = harness.find_cell(name)
+def cell_names(root=ROOT):
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    return [w["name"] for w in spec["workloads"]]
+
+
+CELLS = cell_names()
+
+
+def small_sizes(cell):
+    """The configuration's shape on a few banks: its rows per bank, and
+    its rows per decision, which span all the small cell's rows where
+    they span all the configuration's."""
+    cfg = cell.config
+    n_banks = min(N_BANKS, cfg["n_banks"])
+    sizes = {"n_banks": n_banks,
+             **(SINGLE if cell.traffic["queries_per_call"] == 1 else BATCH)}
+    if cfg["rows_per_decision"] == cfg["n_banks"] * cfg["rows_per_bank"]:
+        sizes["rows_per_decision"] = n_banks * cfg["rows_per_bank"]
+    return sizes
+
+
+def small_run(name, system_cls=harness.Program, seconds=1.0, root=ROOT):
+    cell = harness.find_cell(name, root=root)
     return harness.run(cell, SEED, seconds, False, time.perf_counter(),
-                       sizes=SIZES[name.split(".")[1]],
-                       system_cls=system_cls, check_chip=False)
+                       sizes=small_sizes(cell), system_cls=system_cls,
+                       check_chip=False)
 
 
 @pytest.mark.parametrize("name", CELLS)

@@ -14,11 +14,12 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import harness  # noqa: E402
+from test_bench_correct import SEED, cell_names, small_run, small_sizes  # noqa: E402,E501
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-CELLS = [w["name"] for w in SPEC["workloads"]]
+CELLS = cell_names()
 
 
 def test_top_level_keys():
@@ -65,41 +66,50 @@ def test_every_cell_is_found_whole(cell):
     assert c.app.MODE == c.config["mode"]
 
 
-def test_a_new_cell_is_found_by_name_alone(tmp_path):
-    """A configuration, a traffic mix and a per-layer metric added as new
-    files, and listed in BENCHMARK.json, reach the harness with no edit
-    to any file it already has."""
-    for rel in ("bench",):
-        shutil.copytree(ROOT / rel, tmp_path / rel)
-    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+def add_cell(root, config, reduced, traffic_name, traffic):
+    """A copy of ``bench/`` under ``root`` with one more cell, made of a
+    new configuration, a new traffic mix and a new per-layer reader, and
+    listed in a copy of BENCHMARK.json.  Returns the cell's name and the
+    bytes of every file that the copy held before."""
+    shutil.copytree(ROOT / "bench", root / "bench")
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
               if p.is_file()}
-    spec = json.loads(json.dumps(SPEC))
-    cfg = json.loads((ROOT / "bench/configs/knn-md-32bank.json").read_text())
-    cfg["name"], cfg["n_banks"] = "knn-md-8bank", 8
-    (tmp_path / "bench/configs/knn-md-8bank.json").write_text(
-        json.dumps(cfg))
-    (tmp_path / "bench/traffic/batch16.json").write_text(json.dumps(
-        {"queries_per_call": 16, "pool_calls": 2, "cal_queries": 16,
-         "compared_calls": 2, "loop": "closed", "callers": 1}))
-    (tmp_path / "bench/metrics/calls_per_s.batch.py").write_text(
+    name = f"{config['name']}.{traffic_name}"
+    file = f"bench/configs/{config['name']}.json"
+    (root / file).write_text(json.dumps(config))
+    (root / "bench/traffic" / f"{traffic_name}.json").write_text(json.dumps(
+        {**traffic, "loop": "closed", "callers": 1}))
+    (root / "bench/metrics/calls_per_s.batch.py").write_text(
         "def read(ctx):\n    return ctx['calls'] / ctx['window_s']\n")
-    spec["configs"].append({"name": "knn-md-8bank", "source": "x",
-                            "file": "bench/configs/knn-md-8bank.json",
-                            "reduced": ["n_banks"], "why": "x"})
-    spec["workloads"].append({"name": "knn-md-8bank.batch16",
-                              "config": "knn-md-8bank",
-                              "traffic": "batch16", "chips": 1, "why": "x"})
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": config["name"], "source": "x",
+                            "file": file, "reduced": reduced, "why": "x"})
+    spec["workloads"].append({"name": name, "config": config["name"],
+                              "traffic": traffic_name, "chips": 1,
+                              "why": "x"})
     for m in spec["end_to_end"]:
         if m["name"] == "decisions_per_s":
-            m["workloads"].append("knn-md-8bank.batch16")
+            m["workloads"].append(name)
     spec["per_layer"].append({"name": "calls_per_s.batch", "unit": "1/s",
                               "better": "higher", "source": "host_clock",
                               "layer": "whole step",
                               "moves": "decisions_per_s",
-                              "workloads": ["knn-md-8bank.batch16"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+                              "workloads": [name]})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return name, before
 
-    c = harness.find_cell("knn-md-8bank.batch16", root=tmp_path)
+
+def test_a_new_cell_is_found_by_name_alone(tmp_path):
+    """A configuration, a traffic mix and a per-layer metric added as new
+    files, and listed in BENCHMARK.json, reach the harness with no edit
+    to any file it already has."""
+    cfg = json.loads((ROOT / "bench/configs/knn-md-32bank.json").read_text())
+    cfg["name"], cfg["n_banks"] = "knn-md-8bank", 8
+    name, before = add_cell(tmp_path, cfg, ["n_banks"], "batch16",
+                            {"queries_per_call": 16, "pool_calls": 2,
+                             "cal_queries": 16, "compared_calls": 2})
+
+    c = harness.find_cell(name, root=tmp_path)
     assert c.config["n_banks"] == 8
     assert c.traffic["queries_per_call"] == 16
     assert c.end_to_end == ["decisions_per_s", "setup_s"]
@@ -146,7 +156,60 @@ def test_what_the_harness_does_not_run_is_refused(tmp_path, file, key,
         harness.find_cell("knn-md-32bank.batch256", root=tmp_path)
 
 
+#: the paper's 32-bank scenario: 32 banks of 64 stored vectors
+PAPER_ROWS = {"mf-dp-32bank": 2048, "knn-md-32bank": 2048}
+
+
+def check_geometry(name, root=ROOT):
+    """Set-up at the cell's small size stores the rows and gives the
+    decisions per call that the cell's configuration file states."""
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    w = next(w for w in spec["workloads"] if w["name"] == name)
+    c = next(c for c in spec["configs"] if c["name"] == w["config"])
+    cfg = json.loads((root / c["file"]).read_text())
+    rows = cfg["n_banks"] * cfg["rows_per_bank"]
+    assert rows % cfg["rows_per_decision"] == 0
+    if w["config"] in PAPER_ROWS:
+        assert rows == PAPER_ROWS[w["config"]]
+    cell = harness.find_cell(name, root=root)
+    sizes = small_sizes(cell)
+    st = harness.setup(cell, SEED, sizes)
+    M = sizes["n_banks"] * cfg["rows_per_bank"]
+    per_decision = (M if cfg["rows_per_decision"] == rows
+                    else cfg["rows_per_decision"])
+    assert st["M"] == M
+    assert st["per_call"] == st["B"] * M // per_decision
+    assert st["B"] == sizes.get("queries_per_call",
+                                cell.traffic["queries_per_call"])
+    return st
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_geometry_is_read_from_the_configuration(cell):
-    c = harness.find_cell(cell)
-    assert c.config["n_banks"] * c.config["rows_per_bank"] == 2048
+    check_geometry(cell)
+
+
+def test_a_cell_of_another_geometry_needs_only_new_files(tmp_path):
+    """A configuration of six banks with one decision over all its rows
+    and a traffic mix of 128 queries per call, added as new files, pass
+    the geometry contract and the CPU check of ``correct`` (the program
+    is, the bfloat16 control is not), with no byte changed in any file
+    that was there."""
+    cfg = json.loads((ROOT / "bench/configs/knn-md-32bank.json").read_text())
+    cfg.update(name="knn-md-6bank-global", n_banks=6, rows_per_decision=384)
+    name, before = add_cell(tmp_path, cfg, ["n_banks", "rows_per_decision"],
+                            "batch128",
+                            {"queries_per_call": 128, "pool_calls": 4,
+                             "cal_queries": 256, "compared_calls": 4})
+
+    c = harness.find_cell(name, root=tmp_path)
+    assert c.end_to_end == ["decisions_per_s", "setup_s"]
+    assert list(c.per_layer) == ["calls_per_s.batch"]
+    assert name in cell_names(tmp_path)
+    st = check_geometry(name, root=tmp_path)
+    assert st["per_call"] == st["B"] == 4       # one vote over all rows
+    ok = small_run(name, root=tmp_path)
+    assert ok["correct"], ok["checks"]
+    control = small_run(name, harness.Control, root=tmp_path)
+    assert not control["correct"], control["checks"]
+    assert all(p.read_bytes() == b for p, b in before.items())

@@ -1,16 +1,18 @@
 """The reduction from a profiler trace to busy, idle, kernel time and
 program executions, on a small synthetic XSpace."""
-import os
 import sys
+from pathlib import Path
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
 
+from bench import harness, work  # noqa: E402
 from bench import trace_reduce as tr  # noqa: E402
 
 KERNEL = "%dima_md_bank_batch.1 = (s32[32,256,1,128]) custom-call(u8[32])"
+KERNEL_DP = "%dima_dp_bank_batch.1 = (s32[32,256,1,128]) custom-call(u8[32])"
 NOISE = "%fusion.8 = f32[32,256,128,2,128] fusion(u32[32])"
 COPY = "%copy.1 = s32[32,128] copy(s32[32,128] %bitcast.1)"
 
@@ -75,7 +77,41 @@ def test_busy_is_the_union_of_operations(summary):
 ])
 def test_kernel_time_by_name(summary, patterns, seconds, count):
     assert tr.kernel_s(summary, patterns) == pytest.approx(seconds)
-    assert tr.kernel_count(summary, patterns) == count
+    assert len(tr.matching(summary.ops[0], patterns)) == count
+
+
+def test_calls_are_the_bench_call_spans(summary):
+    assert tr.calls(summary) == 2
+
+
+def _calls(launches, kernel):
+    """Three calls 1000 ns apart; in each, 100 ns of noise, then
+    ``launches`` launches of ``kernel`` that take 400 ns together."""
+    ops, spans, d = [], [], 400 // launches
+    for t in (0, 1000, 2000):
+        spans.append(("bench.call", t, t + 900))
+        ops.append((NOISE, t, t + 100))
+        ops += [(kernel, t + 100 + i * d, t + 100 + (i + 1) * d)
+                for i in range(launches)]
+    return tr.Summary(ops=[ops], modules=[[]], host_spans=spans)
+
+
+@pytest.mark.parametrize("launches", [1, 4])
+@pytest.mark.parametrize("metric,kernel", [("dima_md_roofline", KERNEL),
+                                           ("dima_dp_roofline", KERNEL_DP)],
+                         ids=["md", "dp"])
+def test_roofline_counts_calls_not_launches(metric, kernel, launches):
+    """A kernel launched several times a call reads the share that one
+    launch a call of the same summed time reads: 3 calls' least time
+    over 1200 ns."""
+    reader = harness.load_module(ROOT / "bench" / "metrics" / f"{metric}.py",
+                                 f"bench.metrics.{metric}")
+    ctx = {"B": 256, "M": 2048, "N": 256, "device_kind": "TPU v5 lite"}
+    got = reader.read({**ctx, "summary": _calls(launches, kernel)})
+    one = reader.read({**ctx, "summary": _calls(1, kernel)})
+    assert got == pytest.approx(one)
+    assert one == pytest.approx(
+        100 * 3 * work.min_seconds(256, 2048, 256, "TPU v5 lite") / 1200e-9)
 
 
 def test_program_executions(summary):
